@@ -97,103 +97,72 @@ def run(n_playouts: int = 4096, n_workers: int = 1, board_size: int = 5,
         "single_tree_rates": single_rates,
         "ensemble": points,
     }
-    try:
-        out["sharded_forest"] = sharded_forest(
-            n_playouts=min(n_playouts, 1024), repeats=2)
-    except Exception as e:   # noqa: BLE001 — the scale-out point is an
-        # extra on hosts where spawning workers is restricted; the in-process
-        # sweep above stays the benchmark's headline either way
-        out["sharded_forest"] = {"error": f"{type(e).__name__}: {e}"}
+    out["sharded_forest"] = sharded_forest(
+        n_playouts=min(n_playouts, 1024), repeats=2)
     return out
 
 
 def sharded_forest(n_playouts: int = 1024, n_trees: int = 8,
                    board_size: int = 5, n_tasks: int = 8,
                    n_workers: int = 1, tree_cap: int | None = None,
-                   seed: int = 0, repeats: int = 3,
-                   n_devices: int = 8) -> dict:
+                   seed: int = 0, repeats: int = 3) -> dict | None:
     """shard_map forest scale-out vs the single-device vmap path.
 
-    The device count is fixed when jax initializes, so each point runs in
-    a SUBPROCESS with ``XLA_FLAGS=--xla_force_host_platform_device_count``
-    set before import: one worker on 1 device (``shard="off"``), one on
-    ``n_devices`` virtual host devices (``shard="require"``). The worker
-    reports ``stats["sharded"]`` so the caller can assert the sharded
-    point actually ran sharded, and the merged best move must agree across
-    the two — the bit-identity contract of tests/test_forest_sharding.py,
-    smoked here on every benchmark run.
+    Both points run in THIS process on the devices JAX already sees: one
+    process holds a chip, so a child started after the parent touched JAX
+    could not reach the device. ``shard="off"`` runs the forest on one
+    device, ``shard="require"`` over all of them. Returns None when only
+    one device is visible; on a CPU host the multi-device rehearsal is
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set before the
+    process starts (README "Scaling out"). The merged best move must agree
+    across the two points — the bit-identity contract of
+    tests/test_forest_sharding.py, smoked here on every benchmark run.
     """
-    import json
-    import subprocess
+    n_devices = len(jax.devices())
+    if n_devices < 2:
+        return None
+    kw = dict(n_playouts=n_playouts, board_size=board_size, n_tasks=n_tasks,
+              n_workers=n_workers,
+              tree_cap=tree_cap or max(512, n_playouts // 8))
+    cfg = GSCPMConfig(**kw)
+    board = cfg.game_obj.init_board()
+    key = jax.random.key(seed)
 
-    kw = dict(n_playouts=n_playouts, n_trees=n_trees, board_size=board_size,
-              n_tasks=n_tasks, n_workers=n_workers,
-              tree_cap=tree_cap or max(512, n_playouts // 8), seed=seed,
-              repeats=repeats)
+    def point(shard: str) -> dict:
+        def one():
+            _, st = gscpm_search_batch(board, 1, cfg, key, n_trees=n_trees,
+                                       shard=shard)
+            return st
 
-    def point(devices: int, shard: str) -> dict:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={devices}"
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "src")]
-            + env.get("PYTHONPATH", "").split(os.pathsep))
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--sharded-worker",
-             json.dumps(dict(kw, shard=shard))],
-            env=env, capture_output=True, text=True, timeout=600)
-        if proc.returncode != 0:
-            raise RuntimeError(f"sharded worker failed:\n{proc.stderr}")
-        return json.loads(proc.stdout.strip().splitlines()[-1])
+        one()                                    # compile off the clock
+        stats = [one() for _ in range(repeats)]
+        rates = sorted(s["playouts_per_s"] for s in stats)
+        st = stats[-1]
+        return {
+            "n_devices": st["n_devices"],
+            "sharded": st["sharded"],
+            "mesh_shape": st["mesh_shape"],
+            "padded_members": st["padded_members"],
+            "playouts": st["playouts"],
+            "playouts_per_s": rates[len(rates) // 2],
+            "best_move_sum": st["best_move_sum"],
+            "best_move_vote": st["best_move_vote"],
+        }
 
-    single = point(1, "off")
-    sharded = point(n_devices, "require")
-    assert sharded["sharded"] is True, "sharded point ran unsharded"
-    assert single["sharded"] is False
-    assert sharded["best_move_sum"] == single["best_move_sum"]
-    assert sharded["playouts"] == single["playouts"]
+    single = point("off")
+    sharded = point("require")
+    if (sharded["best_move_sum"], sharded["playouts"]) != (
+            single["best_move_sum"], single["playouts"]):
+        raise RuntimeError(f"sharded forest {sharded} disagrees with the "
+                           f"single-device forest {single}")
     return {
-        "config": dict(kw, n_devices=n_devices),
+        "config": dict(kw, n_trees=n_trees, seed=seed, repeats=repeats,
+                       n_devices=n_devices),
         "single_device": single,
         "sharded": sharded,
         "speedup_vs_single_device": (sharded["playouts_per_s"]
                                      / max(single["playouts_per_s"], 1e-9)),
     }
-
-
-def _sharded_worker(payload: str) -> None:
-    """Subprocess entry: time gscpm_search_batch under this process's
-    device count and print one JSON line."""
-    import json
-
-    kw = json.loads(payload)
-    cfg = GSCPMConfig(board_size=kw["board_size"],
-                      n_playouts=kw["n_playouts"], n_tasks=kw["n_tasks"],
-                      n_workers=kw["n_workers"], tree_cap=kw["tree_cap"])
-    board = cfg.game_obj.init_board()
-    key = jax.random.key(kw["seed"])
-
-    def one():
-        _, st = gscpm_search_batch(board, 1, cfg, key,
-                                   n_trees=kw["n_trees"],
-                                   shard=kw["shard"])
-        return st
-
-    one()                                    # compile off the clock
-    stats = [one() for _ in range(kw["repeats"])]
-    rates = sorted(s["playouts_per_s"] for s in stats)
-    st = stats[-1]
-    print(json.dumps({
-        "n_devices": len(jax.devices()),
-        "sharded": st["sharded"],
-        "mesh_shape": st["mesh_shape"],
-        "padded_members": st["padded_members"],
-        "playouts": st["playouts"],
-        "playouts_per_s": rates[len(rates) // 2],
-        "best_move_sum": st["best_move_sum"],
-        "best_move_vote": st["best_move_vote"],
-    }))
 
 
 def main():
@@ -204,12 +173,7 @@ def main():
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true",
                    help="tiny budgets (CI rot-guard, <1 min)")
-    p.add_argument("--sharded-worker", default=None, metavar="JSON",
-                   help=argparse.SUPPRESS)   # internal subprocess entry
     args = p.parse_args()
-    if args.sharded_worker:
-        _sharded_worker(args.sharded_worker)
-        return
 
     out = run(n_playouts=512, repeats=2) if args.smoke else run()
     base = out["single_tree_playouts_per_s"]
@@ -219,8 +183,8 @@ def main():
               f"aggregate {pt['aggregate_speedup']:5.2f}x   "
               f"batching efficiency {pt['batching_efficiency']:5.1%}")
     sf = out["sharded_forest"]
-    if "error" in sf:
-        print(f"sharded forest: SKIPPED ({sf['error']})")
+    if sf is None:
+        print("sharded forest: not run (one device visible)")
     else:
         print(f"sharded forest: E={sf['config']['n_trees']} over "
               f"{sf['sharded']['n_devices']} devices   "

@@ -160,8 +160,8 @@ def write_mcts_trajectory(results: dict) -> str | None:
         # per-request bit-identity asserted in-run (DESIGN.md §18)
         payload["pipeline"] = results["serve_games"]["pipeline"]
     if "root_parallel" in results:
-        # shard_map forest scale-out point (subprocess workers on 1 and 8
-        # virtual host devices; see root_parallel.sharded_forest)
+        # shard_map forest scale-out point, in-process on the visible
+        # devices (None on one device; see root_parallel.sharded_forest)
         payload["sharded_forest"] = results["root_parallel"].get(
             "sharded_forest")
     if "selfplay" in results:

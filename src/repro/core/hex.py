@@ -129,9 +129,7 @@ def empty_board(spec: HexSpec) -> jnp.ndarray:
     return jnp.zeros(spec.n_cells, dtype=jnp.int8)
 
 
-def place(board: jnp.ndarray, move: jnp.ndarray, player: jnp.ndarray) -> jnp.ndarray:
-    """Place `player`'s stone at flat index `move` (no legality check)."""
-    return board.at[move].set(player.astype(jnp.int8))
+place = game_mod.place    # one-hot stone placement shared by every game
 
 
 def legal_mask(board: jnp.ndarray) -> jnp.ndarray:

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import scheduler as sched
 from repro.core.gscpm import GSCPMConfig, fold_task_keys, sync_iteration
 from repro.core.tree import (
@@ -105,7 +104,7 @@ def ensemble_mesh(devices=None):
     """The 1-D ensemble mesh over all visible devices (None on one device).
 
     Built through ``launch.mesh.make_ensemble_mesh`` — the same
-    ``compat.make_auto_mesh`` path as the LM production meshes, with the
+    ``make_auto_mesh`` path as the LM production meshes, with the
     ``"ens"`` axis the ``sharding/rules.py`` "ensemble" rule maps onto.
     """
     from repro.launch.mesh import make_ensemble_mesh
@@ -175,10 +174,10 @@ def _sharded_chunk(forest, boards, task_keys, active, m, cp, *, cfg, mesh):
     so a member's stream is identical no matter which shard hosts it — the
     bit-identity pin of tests/test_forest_sharding.py."""
     spec, rep = ensemble_spec(mesh), jax.sharding.PartitionSpec()
-    body = compat.shard_map(
+    body = jax.shard_map(
         lambda f, b, k, a, mm, c: _forest_chunk(f, b, cfg, k, a, mm, c),
         mesh=mesh, in_specs=(spec, spec, spec, spec, rep, rep),
-        out_specs=spec)
+        out_specs=spec, check_vma=False)
     return body(forest, boards, task_keys, active, m, cp)
 
 
@@ -190,11 +189,11 @@ def _sharded_chunk_metrics(forest, boards, task_keys, active, m, cp, metrics,
     riding the same ensemble sharding (pad members see only masked-out
     work; callers slice summaries to the real members)."""
     spec, rep = ensemble_spec(mesh), jax.sharding.PartitionSpec()
-    body = compat.shard_map(
+    body = jax.shard_map(
         lambda f, b, k, a, mm, c, mx: _forest_chunk(
             f, b, cfg, k, a, mm, c, mx),
         mesh=mesh, in_specs=(spec, spec, spec, spec, rep, rep, spec),
-        out_specs=(spec, spec))
+        out_specs=(spec, spec), check_vma=False)
     return body(forest, boards, task_keys, active, m, cp, metrics)
 
 
@@ -460,9 +459,8 @@ def gscpm_search_batch(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
     mesh = ensemble_mesh() if shard != "off" else None
     if shard == "require" and mesh is None:
         raise RuntimeError(
-            "shard='require' but fewer than two devices are visible — set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8 BEFORE "
-            "importing jax (README 'Scaling out')")
+            f"shard='require' needs two or more devices; JAX sees "
+            f"{len(jax.devices())} (README 'Scaling out')")
     padded_members = 0
     Ep = E
     if mesh is not None:

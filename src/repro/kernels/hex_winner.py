@@ -84,14 +84,16 @@ def _winner_kernel(board_ref, out_ref, *, size: int, n: int, rounds: int):
     P = jax.lax.fori_loop(0, rounds, one_round, P0)
 
     # black connects top<->bottom iff a bottom black cell's component root
-    # is also some top black cell's root
-    top = black & (r == 0)
-    bottom = black & (r == size - 1)
+    # is also some top black cell's root. The marks are int32 0/1 planes:
+    # Mosaic cannot broadcast a bool (bw, C) vector to (bw, C, 1), so the
+    # one-hot reductions use the same int32 where/max forms as the rounds.
+    top = (black & (r == 0)).astype(jnp.int32)
+    bottom = (black & (r == size - 1)).astype(jnp.int32)
     oh = P[:, :, None] == col
-    mark = jnp.any(oh & top[:, :, None], axis=1)           # (bw, C) roots@top
-    reach = jnp.any(oh & mark[:, None, :], axis=2)         # mark[P[i]]
-    conn = jnp.any(reach & bottom, axis=1, keepdims=True)  # (bw, 1)
-    out_ref[...] = jnp.where(conn, 1, 2).astype(jnp.int32)
+    mark = jnp.max(jnp.where(oh, top[:, :, None], 0), axis=1)    # roots@top
+    reach = jnp.max(jnp.where(oh, mark[:, None, :], 0), axis=2)  # mark[P[i]]
+    conn = jnp.max(reach * bottom, axis=1, keepdims=True)        # (bw, 1)
+    out_ref[...] = jnp.where(conn > 0, 1, 2).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("size", "block_w", "interpret"))

@@ -47,7 +47,13 @@ def _uct_kernel(cp_ref, wins_ref, visits_ref, vloss_ref, ptot_ref, valid_ref,
     score = x_j + explore + noise
     score = jnp.where(n_j <= 0.0, BIG + noise, score)   # unvisited first
     score = jnp.where(valid, score, -BIG)               # masked slots last
-    out_ref[...] = jnp.argmax(score, axis=1, keepdims=True).astype(jnp.int32)
+    # the lowest slot among the maxima, as jnp.argmax breaks ties. Mosaic's
+    # argmax picks another slot among equal scores (seen on a v5e chip on
+    # all-masked rows), so the tie-break is spelled out
+    best = jnp.max(score, axis=1, keepdims=True)
+    slot = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    out_ref[...] = jnp.min(jnp.where(score == best, slot, score.shape[1]),
+                           axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))

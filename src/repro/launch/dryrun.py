@@ -38,7 +38,7 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat, configs
+from repro import configs
 from repro.launch import inputs as inp
 from repro.launch.mesh import make_production_mesh, mesh_dims
 from repro.models import api
@@ -120,7 +120,7 @@ def analyze_cell(arch: str, shape_name: str, mesh, mesh_name: str,
     t_compile = time.perf_counter() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     parsed = hlo_costs.rollup(hlo)
 
@@ -129,7 +129,7 @@ def analyze_cell(arch: str, shape_name: str, mesh, mesh_name: str,
         lo_u, *_ = lower_cell(arch, shape_name, mesh, True, cfg_overrides,
                               rules_overrides)
         crosscheck_flops = float(
-            compat.cost_analysis_dict(lo_u).get("flops", 0.0))
+            lo_u.cost_analysis().get("flops", 0.0))
 
     shape = spec["shape"]
     chips = len(mesh.devices.flatten())

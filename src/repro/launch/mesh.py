@@ -18,8 +18,16 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_auto_mesh
+
+def make_auto_mesh(shape, axis_names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated
+    shardings): ``jax.make_mesh`` would otherwise pick explicit axes."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,6 +27,7 @@ from repro.core import game as game_mod
 from repro.core.gscpm import GSCPMConfig, gscpm_search
 from repro.core.root_parallel import gscpm_search_batch
 from repro.core.tree import reroot_forest, reroot_tree
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -66,6 +67,7 @@ def main():
                    help="record per-round spans as Chrome/Perfetto trace-"
                         "event JSON (blocks per round while tracing)")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = GSCPMConfig(game=args.game, board_size=args.size,
                       n_playouts=args.playouts, n_tasks=args.tasks,

@@ -26,6 +26,7 @@ import argparse
 import time
 
 from repro.core import game as game_mod
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def play_game(eng, game: str, size: int, *, playouts: tuple[int, int],
@@ -123,6 +124,7 @@ def main():
     p.add_argument("--trace", default=None, metavar="OUT.json",
                    help="Chrome/Perfetto trace of the whole self-play run")
     args = p.parse_args()
+    enable_compile_cache()
 
     tracer = None
     if args.trace:

@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve.engine import MCTSSlotEngine, Request, SlotEngine
 from repro.serve.mcts_decode import MCTSDecodeConfig
@@ -111,6 +112,7 @@ def main():
                    help="quarantine a slot after this many consecutive "
                         "quantum failures (the engine serves on survivors)")
     args = p.parse_args()
+    enable_compile_cache()
     args.tracer, args.registry = make_observers(args)
 
     if args.mcts_game:

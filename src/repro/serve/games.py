@@ -513,13 +513,20 @@ class TPFIFOGameEngine(TPFIFODriver):
                 # contained to ITS slot — the search rolls back to its last
                 # committed snapshot and requeues with backoff, the slot
                 # takes a quarantine strike, and every other slot's quantum
-                # still runs
+                # still runs. A quantum that fails again exactly as its
+                # previous attempt did (a compile error, a bad shape) is
+                # deterministic: retrying cannot help, so the error ends
+                # the run instead of requeueing forever.
                 try:
                     self._run_slot(t, m, slot_key=(ck, s))
                 except Exception as err:  # noqa: BLE001 — containment seam
+                    if t.last_failure == repr(err):
+                        raise
+                    t.last_failure = repr(err)
                     self._fail_slot(ck, s, t, err)
                     failed.add(t.req.rid)
                 else:
+                    t.last_failure = None
                     self._note_slot_ok((ck, s))
             for ck, s, t in live:
                 if t.req.rid in failed:

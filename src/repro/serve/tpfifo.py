@@ -84,6 +84,8 @@ class Ticket:
                                         # guard rejections) — NOT preemptions
     not_before: int = 0                 # earliest tick this ticket may be
                                         # re-admitted (retry backoff gate)
+    last_failure: str | None = None     # repr of the previous attempt's
+                                        # error, if that attempt failed
     seg_base: int = 0                   # len(req.out) at current admission
     plan: list[int] | None = None       # remaining quantum sizes
     plan_idx: int = 0

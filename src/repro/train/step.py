@@ -25,7 +25,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import api
 from repro.models.common import ModelConfig
 from repro.optim import adamw
@@ -133,13 +132,13 @@ def make_train_step_compressed(cfg: ModelConfig, opt_cfg: adamw.OptConfig,
 
     def step(state: dict, batch: dict):
         batch_specs = {k: P(pod_axis) for k in batch}
-        f = compat.shard_map(
+        f = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), state), batch_specs),
             out_specs=(jax.tree.map(lambda _: P(), state),
                        {"loss": P(), "grad_norm": P(), "lr": P(),
                         "skipped": P()}),
-            axis_names={pod_axis}, check=False)
+            axis_names={pod_axis}, check_vma=False)
         return f(state, batch)
 
     return step

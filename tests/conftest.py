@@ -1,8 +1,8 @@
 """Pytest bootstrap: make src/ and the tests dir importable everywhere.
 
 Keeps `PYTHONPATH=src python -m pytest` (the tier-1 command) and a bare
-`pytest` invocation equivalent, and lets test modules import the local
-`hypcompat` shim regardless of pytest's import mode.
+`pytest` invocation equivalent, and lets test modules import helpers from
+one another regardless of pytest's import mode.
 """
 
 import os

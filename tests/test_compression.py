@@ -10,7 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.optim.compression import dequantize_int8, quantize_int8
 
@@ -46,8 +47,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
-from repro.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.optim.compression import compressed_psum
 
 mesh = make_auto_mesh((4, 2), ("pod", "data"))
@@ -58,32 +58,29 @@ def f(x):
     exact = jax.lax.psum(x, "pod")
     return comp, exact
 
-g = compat.shard_map(f, mesh=mesh, in_specs=P("pod"),
-                     out_specs=(P("pod"), P("pod")), check=False)
+g = jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                  out_specs=(P("pod"), P("pod")), check_vma=False)
 comp, exact = g(x)
 err = float(jnp.max(jnp.abs(comp - exact)))
 scale = float(jnp.max(jnp.abs(exact))) + 1e-9
 assert err / scale < 0.05, (err, scale)
 
-# compressed train step lowers + compiles on a pod mesh. Requires the
-# modern partial-auto shard_map: jax 0.4.x's experimental `auto=` path
-# trips an XLA CHECK (IsManualSubgroup) on this program, so only the
-# numeric psum half runs there.
-if hasattr(jax, "shard_map"):
-    from repro import configs
-    from repro.optim.adamw import OptConfig
-    from repro.train import step as sm
-    cfg = configs.reduced_config("smollm-135m").replace(n_layers=2)
-    mesh3 = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
-    step = sm.make_train_step_compressed(cfg, OptConfig(), mesh3)
-    state = sm.abstract_state(cfg)
-    batch = {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32),
-             "labels": jax.ShapeDtypeStruct((8, 32), jnp.int32),
-             "mask": jax.ShapeDtypeStruct((8, 32), jnp.float32)}
-    compiled = jax.jit(step).lower(state, batch).compile()
-    txt = compiled.as_text()
-    assert "all-gather" in txt  # the int8 wire path
-    assert "s8[" in txt, "int8 payload missing from the compiled module"
+# the compressed train step (shard_map manual over the pod axis only)
+# lowers + compiles on a (pod, data, model) mesh
+from repro import configs
+from repro.optim.adamw import OptConfig
+from repro.train import step as sm
+cfg = configs.reduced_config("smollm-135m").replace(n_layers=2)
+mesh3 = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
+step = sm.make_train_step_compressed(cfg, OptConfig(), mesh3)
+state = sm.abstract_state(cfg)
+batch = {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32),
+         "labels": jax.ShapeDtypeStruct((8, 32), jnp.int32),
+         "mask": jax.ShapeDtypeStruct((8, 32), jnp.float32)}
+compiled = jax.jit(step).lower(state, batch).compile()
+txt = compiled.as_text()
+assert "all-gather" in txt  # the int8 wire path
+assert "s8[" in txt, "int8 payload missing from the compiled module"
 print("OK", err / scale)
 """
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))

@@ -20,7 +20,7 @@ SCRIPT = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
-from repro.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 from repro.launch import inputs as inp
 from repro.launch import dryrun
 from repro.roofline import hlo_costs

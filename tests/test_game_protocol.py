@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import game as game_mod
 from repro.core.gscpm import GSCPMConfig, gscpm_search
@@ -119,6 +120,30 @@ def test_legal_place_roundtrip(name):
             np.testing.assert_array_equal(
                 np.delete(np.asarray(b2), mv), np.delete(np.asarray(b), mv))
             assert not bool(g.legal_mask(b2)[mv])
+
+
+@pytest.mark.parametrize("name", GAMES)
+def test_batched_place_sets_exactly_one_cell_per_board(name):
+    """``place`` under a two-level vmap (forest x lanes, the search's own
+    batching) writes each board's move and nothing else; a move off the
+    board writes nothing."""
+    g = make(name)
+    rng = np.random.default_rng(3)
+    E, W = 3, 5
+    boards = np.stack([np.stack([np.asarray(random_board(g, rng, 0.4))
+                                 for _ in range(W)]) for _ in range(E)])
+    moves = rng.integers(0, g.n_cells, (E, W)).astype(np.int32)
+    moves[0, 0] = -1
+    moves[1, 2] = g.n_cells
+    players = rng.integers(1, 3, (E, W)).astype(np.int8)
+    got = np.asarray(jax.jit(jax.vmap(jax.vmap(g.place)))(
+        jnp.asarray(boards), jnp.asarray(moves), jnp.asarray(players)))
+    want = boards.copy()
+    for e in range(E):
+        for w in range(W):
+            if 0 <= moves[e, w] < g.n_cells:
+                want[e, w, moves[e, w]] = players[e, w]
+    np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("name", GAMES)
@@ -377,8 +402,14 @@ def test_gomoku_all_draw_search_is_exactly_half():
 
 def test_gomoku_finds_immediate_win():
     """Black has an open four on row 3 of a 7x7 board; either extension
-    (cells 21 / 26) wins outright — the winning child's value is exactly 1
-    (every playout from a won position returns its pre-existing winner)."""
+    (cells 21 / 26) wins outright — each winning child's value is exactly 1
+    (every playout from a won position returns its pre-existing winner),
+    and UCT gives each at least an even share of the root's visits.
+
+    Which move ends up most visited is seed luck at this budget: with an
+    open four nearly every random playout is a black win, so many other
+    children also score 1.0 over their ~12 visits and the argmax among
+    them is decided by the random stream, not by the search."""
     size = 7
     b = np.zeros(size * size, dtype=np.int8)
     for c in (1, 2, 3, 4):
@@ -388,11 +419,13 @@ def test_gomoku_finds_immediate_win():
     cfg = GSCPMConfig(game="gomoku", board_size=size, n_playouts=512,
                       n_tasks=16, n_workers=8, tree_cap=8192)
     tree, stats = gscpm_search(jnp.asarray(b), 1, cfg, jax.random.PRNGKey(2))
-    win_moves = (3 * size + 0, 3 * size + 5)
-    assert stats["best_move"] in win_moves
     kids = np.asarray(tree.children[0][: int(tree.n_children[0])])
-    j = kids[list(np.asarray(tree.move)[kids]).index(stats["best_move"])]
-    assert float(tree.wins[j]) == float(tree.visits[j]) > 0
+    moves = list(np.asarray(tree.move)[kids])
+    visits = np.asarray(tree.visits)[kids]
+    for mv in (3 * size + 0, 3 * size + 5):
+        j = kids[moves.index(mv)]
+        assert float(tree.wins[j]) == float(tree.visits[j]) > 0
+        assert float(tree.visits[j]) >= visits.mean()
 
 
 def test_gomoku_won_position_is_terminal_not_expanded():

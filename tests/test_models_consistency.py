@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models import ssm, xlstm
 from repro.models.common import ModelConfig, init_tree, spec_with_dtype

@@ -255,6 +255,8 @@ def test_session_served_warm_matches_direct_reference():
     t0, _ = gscpm_search(c.game_obj.init_board(), 1, c, jax.random.key(11))
     warm = reroot_tree(t0, mv)
     reused = float(warm.visits[0])
+    # read before the warm search: gscpm_search donates the tree's buffers
+    warm_nodes = int(warm.n_nodes)
     eff_po, eff_tasks = warm_budget(64, 8, c.n_workers, reused)
     c1 = dataclasses.replace(c, n_playouts=eff_po, n_tasks=eff_tasks)
     board1 = c.game_obj.place(c.game_obj.init_board(), jnp.int32(mv),
@@ -267,7 +269,7 @@ def test_session_served_warm_matches_direct_reference():
     assert r1["best_move"] == ref["best_move"]
     assert r1["tree_nodes"] == ref["tree_nodes"]
     assert r1["reused_visits"] == int(reused) > 0
-    assert r1["reused_nodes"] == int(warm.n_nodes) - 1 > 0
+    assert r1["reused_nodes"] == warm_nodes - 1 > 0
     # equal-evidence accounting: the served search committed exactly the
     # reference's fresh-playout schedule (make_schedule may round eff_po)
     assert r1["playouts"] == s1["playouts"] < 64

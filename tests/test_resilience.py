@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import scheduler
 from repro.core.gscpm import gscpm_search, run_chunk

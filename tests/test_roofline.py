@@ -39,8 +39,7 @@ def test_unrolled_matches_xla():
     b = jax.ShapeDtypeStruct((512, 128), jnp.float32)
     compiled = jax.jit(f).lower(a, b).compile()
     got = hlo_costs.rollup(compiled.as_text())
-    from repro.compat import cost_analysis_dict
-    xla = cost_analysis_dict(compiled)["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert 0.5 * xla <= got.flops <= 2.0 * xla, (got.flops, xla)
 
 

@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypcompat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import scheduler
 from repro.core.gscpm import gscpm_search, run_chunk
@@ -309,3 +310,41 @@ def test_property_mixed_traffic_never_starves(seed, slots, grain, preempt):
         submitted = [r.rid for r in reqs if r.game == g]
         admitted = [rid for rid in first_admissions if by_game[rid] == g]
         assert admitted == submitted
+
+
+def test_quantum_failing_the_same_way_every_attempt_ends_run():
+    """A deterministic quantum failure (a compile error, a bad shape) is
+    raised out of ``run()`` on its second identical attempt instead of
+    being requeued forever."""
+    calls = []
+
+    def broken(tree, board, cfg, key, rnd, cp):
+        calls.append(rnd)
+        raise ValueError("Mosaic failed to compile TPU kernel")
+
+    with mock.patch("repro.serve.games.run_schedule_round", broken):
+        eng = engine(tree_cap=64, guard=False, retry_backoff=(1, 1))
+        eng.submit(req("broken", seed=0))
+        with pytest.raises(ValueError, match="Mosaic failed"):
+            eng.run(max_ticks=100)
+    assert len(calls) == 2
+    assert eng.stats().n_retries == 1
+
+
+def test_quantum_failing_once_is_retried_and_answered():
+    """A failure that does not repeat is contained: the request is retried
+    from a cold rebuild and still answered."""
+    fails = ["transient device error"]
+
+    def flaky(tree, board, cfg, key, rnd, cp):
+        if fails:
+            raise RuntimeError(fails.pop())
+        return tree
+
+    with mock.patch("repro.serve.games.run_schedule_round", flaky):
+        eng = engine(tree_cap=64, guard=False, retry_backoff=(1, 1))
+        r = req("flaky", seed=0)
+        eng.submit(r)
+        eng.run(max_ticks=100)
+    assert r.result["status"] == "answered"
+    assert r.result["retries"] == 1
