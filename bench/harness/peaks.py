@@ -1,0 +1,21 @@
+"""Published peaks per chip, keyed by the ``device_kind`` JAX reports.
+
+Source: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s bf16,
+393 TOP/s int8, 16 GB HBM at 819 GB/s). A kind that is not here is an
+error, never a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flop_s": 197e12, "int8_op_s": 393e12,
+                    "hbm_bytes_s": 819e9, "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"add them to bench/harness/peaks.py with their source")
