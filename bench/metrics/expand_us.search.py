@@ -1,0 +1,11 @@
+"""Device time of the ``expand`` phase of one sync iteration, in microseconds:
+the deduplicating expansion (its two-key sort, the scatters into the tree,
+the path patch). The leaf ops under that scope in the ``run_chunk`` programs
+wholly inside the traced window, over the sync iterations those programs ran
+(harness.phases); None where the program names no phases."""
+
+from harness import phases
+
+
+def read(ctx):
+    return phases.phase_us(ctx, "expand")

@@ -33,7 +33,6 @@ the paper's Table I; the scheduling disciplines live in
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -59,6 +58,7 @@ from repro.core.tree import (
     root_value,
 )
 from repro.kernels import ops
+from repro.obsv.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -395,13 +395,20 @@ def sync_iteration(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
         out = select_group(tr, keys_g)
         paths = out[0]
         if R > 1:
-            tr = add_vloss(tr, paths, act_g.astype(jnp.float32),
-                           cfg.virtual_loss)
+            with jax.named_scope("backup"):
+                tr = add_vloss(tr, paths, act_g.astype(jnp.float32),
+                               cfg.virtual_loss)
         return tr, out
 
-    tree, outs = jax.lax.scan(round_body, tree, (keys_r, active_r))
+    # one named scope per phase (descent, expand, leaf_eval, backup): the
+    # compiled ops carry it in their op_name metadata, so a device trace
+    # attributes each op to its phase; metadata only, the program's values
+    # are the same. An op under two phases belongs to the inner one.
+    with jax.named_scope("descent"):
+        tree, outs = jax.lax.scan(round_body, tree, (keys_r, active_r))
     if R > 1:
-        tree = reset_vloss(tree)
+        with jax.named_scope("backup"):
+            tree = reset_vloss(tree)
 
     paths = outs[0].reshape(W, -1)
     depths = outs[1].reshape(W)
@@ -411,32 +418,35 @@ def sync_iteration(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
     po_keys = outs[5].reshape(W, *outs[5].shape[2:])
 
     n_nodes_before = tree.n_nodes
-    tree, new_ids = expand_batch(tree, leaves, moves, active)
+    with jax.named_scope("expand"):
+        tree, new_ids = expand_batch(tree, leaves, moves, active)
 
-    expanded = new_ids < tree.cap
-    # the new node joins the backup path
-    paths = jnp.where(
-        jnp.arange(paths.shape[1])[None, :] == (depths + 1)[:, None],
-        jnp.where(expanded[:, None], new_ids[:, None], tree.cap),
-        paths)
+        expanded = new_ids < tree.cap
+        # the new node joins the backup path
+        paths = jnp.where(
+            jnp.arange(paths.shape[1])[None, :] == (depths + 1)[:, None],
+            jnp.where(expanded[:, None], new_ids[:, None], tree.cap),
+            paths)
 
-    # place each lane's proposed move (if any) — game-agnostic given the
-    # shared board convention; lanes that proposed nothing evaluate the
-    # leaf position itself (terminal leaves included)
-    movers = tree.to_move[leaves]
-    do = moves >= 0
-    placed = jax.vmap(game.place)(boards, jnp.maximum(moves, 0), movers)
-    b2 = jnp.where(do[:, None], placed, boards)
-    nxt = jnp.where(do, 3 - movers, movers)
-    if cfg.playout == "scalar":
-        # per-lane oracle: W interleaved scalar playouts under vmap
-        winners = jax.vmap(game.playout_scalar)(b2, nxt, po_keys)
-    else:
-        # fused leaf evaluation: ONE batched (W, cells) playout stage for
-        # all W lanes (bit-identical values to the oracle above —
-        # tests/test_game_protocol.py)
-        winners = game.playout_batch(b2, nxt, po_keys)
-    tree = backup_paths(tree, paths, winners, active.astype(jnp.float32))
+    with jax.named_scope("leaf_eval"):
+        # place each lane's proposed move (if any) — game-agnostic given
+        # the shared board convention; lanes that proposed nothing evaluate
+        # the leaf position itself (terminal leaves included)
+        movers = tree.to_move[leaves]
+        do = moves >= 0
+        placed = jax.vmap(game.place)(boards, jnp.maximum(moves, 0), movers)
+        b2 = jnp.where(do[:, None], placed, boards)
+        nxt = jnp.where(do, 3 - movers, movers)
+        if cfg.playout == "scalar":
+            # per-lane oracle: W interleaved scalar playouts under vmap
+            winners = jax.vmap(game.playout_scalar)(b2, nxt, po_keys)
+        else:
+            # fused leaf evaluation: ONE batched (W, cells) playout stage
+            # for all W lanes (bit-identical values to the oracle above —
+            # tests/test_game_protocol.py)
+            winners = game.playout_batch(b2, nxt, po_keys)
+    with jax.named_scope("backup"):
+        tree = backup_paths(tree, paths, winners, active.astype(jnp.float32))
     if metrics is None:
         return tree
     from repro.obsv.search_metrics import accumulate_iteration
@@ -549,66 +559,74 @@ def gscpm_search(board: jnp.ndarray, to_move: int, cfg: GSCPMConfig,
 
     ``cfg.metrics`` adds a device-plane ``SearchMetrics`` summary under
     ``stats["metrics"]`` (one host readback at the end of the search).
-    ``tracer`` (a ``repro.obsv.TraceRecorder``) records one ``gscpm_round``
-    span per schedule round, annotated with the round's work so
-    ``obsv.profile`` can fit the measured dispatch burden; tracing blocks
-    on the device after every round to attribute device time to its round
-    — a profiling mode, not the fastest way to run a search.
+
+    Host spans (``repro.obsv.trace.span``, always on and non-blocking) mark
+    the search on the profiler's clock: ``gscpm_search`` around the call,
+    ``search_init``, one ``gscpm_round`` per schedule round (args
+    ``round``, ``m``, ``tasks``), ``search_wait`` on the final device sync
+    and ``search_stats`` around the readbacks. ``tracer`` (a
+    ``repro.obsv.TraceRecorder``) also records the ``gscpm_round`` spans,
+    annotated with the round's work so ``obsv.profile`` can fit the
+    measured dispatch burden; it then blocks on the device after every
+    round to attribute device time to its round — a profiling mode, not
+    the fastest way to run a search.
     """
-    reused_nodes = 0
-    reused_visits = 0.0
-    if tree is None:
-        tree = init_tree(cfg.tree_cap, cfg.game_obj.n_actions, to_move)
-    else:
-        warm_tree_check(tree, to_move, cfg)
-        reused_nodes = int(tree.n_nodes) - 1   # cold trees also own the root
-        reused_visits = float(tree.visits[0])
-    metrics = None
-    if cfg.metrics:
-        from repro.obsv.search_metrics import init_search_metrics
-        metrics = init_search_metrics(tree_nodes_reused=reused_nodes)
-    schedule = sched.make_schedule(
-        cfg.n_playouts, cfg.n_tasks, cfg.n_workers, cfg.scheduler)
+    with span("gscpm_search"):
+        with span("search_init"):
+            reused_nodes = 0
+            reused_visits = 0.0
+            if tree is None:
+                tree = init_tree(cfg.tree_cap, cfg.game_obj.n_actions,
+                                 to_move)
+            else:
+                warm_tree_check(tree, to_move, cfg)
+                reused_nodes = int(tree.n_nodes) - 1   # cold trees own a root
+                reused_visits = float(tree.visits[0])
+            metrics = None
+            if cfg.metrics:
+                from repro.obsv.search_metrics import init_search_metrics
+                metrics = init_search_metrics(tree_nodes_reused=reused_nodes)
+            schedule = sched.make_schedule(
+                cfg.n_playouts, cfg.n_tasks, cfg.n_workers, cfg.scheduler)
 
-    cp = jnp.asarray(cfg.cp, jnp.float32)
-    t0 = time.perf_counter()
-    playouts = 0
-    masked_lane_iters = 0
-    for rnd in schedule:
-        span = (tracer.span("gscpm_round", {
-            "rounds": 1, "iterations": int(rnd.m),
-            "lane_iterations": int(rnd.active.sum()) * rnd.m,
-            "tasks": int(rnd.active.sum()), "workers": cfg.n_workers,
-            "game": cfg.game}) if tracer else contextlib.nullcontext())
-        with span:
-            out = run_schedule_round(tree, board, cfg, key, rnd, cp, metrics)
-            tree, metrics = out if cfg.metrics else (out, metrics)
+        cp = jnp.asarray(cfg.cp, jnp.float32)
+        t0 = time.perf_counter()
+        playouts = 0
+        masked_lane_iters = 0
+        for r, rnd in enumerate(schedule):
+            m, tasks = int(rnd.m), int(rnd.active.sum())
+            with span("gscpm_round", tracer, round=r, m=m, tasks=tasks,
+                      rounds=1, iterations=m, workers=cfg.n_workers):
+                out = run_schedule_round(tree, board, cfg, key, rnd, cp,
+                                         metrics)
+                tree, metrics = out if cfg.metrics else (out, metrics)
+                if tracer:
+                    jax.block_until_ready(tree.visits)
             if tracer:
-                jax.block_until_ready(tree.visits)
-        if tracer:
-            tracer.poll_compiles()
-        playouts += int(rnd.active.sum()) * rnd.m
-        masked_lane_iters += int((~rnd.active).sum()) * rnd.m
-    jax.block_until_ready(tree.visits)
-    dt = time.perf_counter() - t0
+                tracer.poll_compiles()
+            playouts += tasks * m
+            masked_lane_iters += (cfg.n_workers - tasks) * m
+        with span("search_wait"):
+            jax.block_until_ready(tree.visits)
+        dt = time.perf_counter() - t0
 
-    stats = {
-        "time_s": dt,
-        "playouts": playouts,
-        "playouts_per_s": playouts / max(dt, 1e-9),
-        "rounds": len(schedule),
-        "grain": cfg.grain,
-        "masked_lane_fraction": masked_lane_iters
-        / max(1, playouts + masked_lane_iters),
-        "tree_nodes": int(tree.n_nodes),
-        "root_value": float(root_value(tree)),
-        "best_move": int(best_child(tree)),
-    }
-    if reused_nodes or reused_visits:
-        stats["reused_nodes"] = reused_nodes
-        stats["reused_visits"] = reused_visits
-    if cfg.metrics:
-        from repro.obsv.search_metrics import summarize_metrics
-        stats["metrics"] = summarize_metrics(metrics)
+        with span("search_stats"):
+            stats = {
+                "time_s": dt,
+                "playouts": playouts,
+                "playouts_per_s": playouts / max(dt, 1e-9),
+                "rounds": len(schedule),
+                "grain": cfg.grain,
+                "masked_lane_fraction": masked_lane_iters
+                / max(1, playouts + masked_lane_iters),
+                "tree_nodes": int(tree.n_nodes),
+                "root_value": float(root_value(tree)),
+                "best_move": int(best_child(tree)),
+            }
+            if reused_nodes or reused_visits:
+                stats["reused_nodes"] = reused_nodes
+                stats["reused_visits"] = reused_visits
+            if cfg.metrics:
+                from repro.obsv.search_metrics import summarize_metrics
+                stats["metrics"] = summarize_metrics(metrics)
     return tree, stats
-

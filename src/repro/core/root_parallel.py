@@ -31,7 +31,6 @@ see ``repro.serve.mcts_decode.mcts_decode_search_batch`` for the LM twin).
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 from typing import Any, NamedTuple
@@ -51,6 +50,7 @@ from repro.core.tree import (
     root_move_stats,
     root_value,
 )
+from repro.obsv.trace import span
 
 
 # ----------------------------------------------------------- forest chunk ----
@@ -351,6 +351,7 @@ def init_sync_state(n_trees: int, n_moves: int) -> RootSyncState:
 
 
 @functools.partial(jax.jit, static_argnames=("n_moves",))
+@jax.named_scope("merge")
 def sync_root_stats(forest: Tree, state: RootSyncState, n_moves: int
                     ) -> tuple[Tree, RootSyncState]:
     """Refresh every member's root stats with the other members' own work.
@@ -427,9 +428,9 @@ def gscpm_search_batch(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
     and the only cross-shard exchange is ``sync_root_stats``' exact
     delta-tracked merge, whose integer/half-integer float32 sums are
     order-independent. ``cfg.metrics`` adds a whole-ensemble
-    ``stats["metrics"]`` summary; ``tracer`` records per-round
-    ``gscpm_round`` spans (blocking per round, a profiling mode — see
-    ``gscpm.gscpm_search``).
+    ``stats["metrics"]`` summary. The host spans are
+    ``gscpm.gscpm_search``'s, under the same names; ``tracer`` also records
+    the ``gscpm_round`` spans (blocking per round, a profiling mode).
     """
     boards = jnp.asarray(boards)
     if boards.ndim == 1:
@@ -444,105 +445,113 @@ def gscpm_search_batch(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
                          f"got {shard!r}")
     n_moves = cfg.game_obj.n_actions  # the Game seam's move-id space
 
-    reused_nodes = 0
-    if forest is None:
-        forest = init_forest(E, cfg.tree_cap, n_moves, to_move)
-    else:
-        if forest_size(forest) != E:
-            raise ValueError(
-                f"warm forest has {forest_size(forest)} members, "
-                f"boards batch has {E}")
-        from repro.core.gscpm import warm_tree_check
-        tm = int(np.asarray(to_move).reshape(-1)[0])
-        warm_tree_check(forest, tm, cfg)
-        reused_nodes = int(np.asarray(forest.n_nodes).sum()) - E
-    mesh = ensemble_mesh() if shard != "off" else None
-    if shard == "require" and mesh is None:
-        raise RuntimeError(
-            f"shard='require' needs two or more devices; JAX sees "
-            f"{len(jax.devices())} (README 'Scaling out')")
-    padded_members = 0
-    Ep = E
-    if mesh is not None:
-        sharding, Ep = ensemble_sharding(E, mesh)
-        padded_members = Ep - E
-        forest, boards = pad_forest_members(forest, boards, Ep, cfg, to_move)
-        member_keys = fold_task_keys(key, jnp.arange(Ep, dtype=jnp.int32))
-        forest, boards, member_keys = jax.device_put(
-            (forest, boards, member_keys), sharding)
-    else:
-        member_keys = fold_task_keys(key, jnp.arange(E, dtype=jnp.int32))
-    schedule = sched.make_schedule(
-        cfg.n_playouts, cfg.n_tasks, cfg.n_workers, cfg.scheduler)
-    state = init_sync_state(Ep, n_moves) if merge_every > 0 else None
-    metrics = None
-    if cfg.metrics:
-        from repro.obsv.search_metrics import init_search_metrics_forest
-        metrics = init_search_metrics_forest(Ep)
-        if reused_nodes:
-            # per-member retention gauge (summed in the ensemble summary;
-            # pad members report 0 — their forests are fresh inits)
-            metrics = metrics._replace(
-                tree_nodes_reused=(forest.n_nodes - 1).astype(jnp.int32))
+    with span("gscpm_search"):
+        with span("search_init"):
+            reused_nodes = 0
+            if forest is None:
+                forest = init_forest(E, cfg.tree_cap, n_moves, to_move)
+            else:
+                if forest_size(forest) != E:
+                    raise ValueError(
+                        f"warm forest has {forest_size(forest)} members, "
+                        f"boards batch has {E}")
+                from repro.core.gscpm import warm_tree_check
+                tm = int(np.asarray(to_move).reshape(-1)[0])
+                warm_tree_check(forest, tm, cfg)
+                reused_nodes = int(np.asarray(forest.n_nodes).sum()) - E
+            mesh = ensemble_mesh() if shard != "off" else None
+            if shard == "require" and mesh is None:
+                raise RuntimeError(
+                    f"shard='require' needs two or more devices; JAX sees "
+                    f"{len(jax.devices())} (README 'Scaling out')")
+            padded_members = 0
+            Ep = E
+            if mesh is not None:
+                sharding, Ep = ensemble_sharding(E, mesh)
+                padded_members = Ep - E
+                forest, boards = pad_forest_members(forest, boards, Ep, cfg,
+                                                    to_move)
+                member_keys = fold_task_keys(key,
+                                             jnp.arange(Ep, dtype=jnp.int32))
+                forest, boards, member_keys = jax.device_put(
+                    (forest, boards, member_keys), sharding)
+            else:
+                member_keys = fold_task_keys(key,
+                                             jnp.arange(E, dtype=jnp.int32))
+            schedule = sched.make_schedule(
+                cfg.n_playouts, cfg.n_tasks, cfg.n_workers, cfg.scheduler)
+            state = init_sync_state(Ep, n_moves) if merge_every > 0 else None
+            metrics = None
+            if cfg.metrics:
+                from repro.obsv.search_metrics import (
+                    init_search_metrics_forest)
+                metrics = init_search_metrics_forest(Ep)
+                if reused_nodes:
+                    # per-member retention gauge (summed in the ensemble
+                    # summary; pad members report 0 — their forests are
+                    # fresh inits)
+                    metrics = metrics._replace(
+                        tree_nodes_reused=(forest.n_nodes - 1).astype(
+                            jnp.int32))
 
-    cp = jnp.asarray(cfg.cp, jnp.float32)
-    t0 = time.perf_counter()
-    playouts_per_tree = 0
-    n_syncs = 0
-    for r, rnd in enumerate(schedule):
-        span_args = {"rounds": 1, "iterations": int(rnd.m),
-                     "lane_iterations": E * int(rnd.active.sum()) * rnd.m,
-                     "tasks": E * int(rnd.active.sum()),
-                     "workers": E * cfg.n_workers, "game": cfg.game}
-        with (tracer.span("gscpm_round", span_args) if tracer
-              else contextlib.nullcontext()):
-            out = run_schedule_round_forest(forest, boards, cfg, member_keys,
-                                            rnd, cp, metrics, n_real=E,
-                                            mesh=mesh)
-            forest, metrics = out if cfg.metrics else (out, metrics)
-            if tracer:
-                jax.block_until_ready(forest.visits)
-        playouts_per_tree += int(rnd.active.sum()) * rnd.m
-        if merge_every > 0 and ((r + 1) % merge_every == 0
-                                or r == len(schedule) - 1):
-            forest, state = sync_root_stats(forest, state, n_moves)
-            n_syncs += 1
-    jax.block_until_ready(forest.visits)
-    dt = time.perf_counter() - t0
+        cp = jnp.asarray(cfg.cp, jnp.float32)
+        t0 = time.perf_counter()
+        playouts_per_tree = 0
+        n_syncs = 0
+        for r, rnd in enumerate(schedule):
+            m, tasks = int(rnd.m), int(rnd.active.sum())
+            with span("gscpm_round", tracer, round=r, m=m, tasks=E * tasks,
+                      rounds=1, iterations=m, workers=E * cfg.n_workers):
+                out = run_schedule_round_forest(forest, boards, cfg,
+                                                member_keys, rnd, cp, metrics,
+                                                n_real=E, mesh=mesh)
+                forest, metrics = out if cfg.metrics else (out, metrics)
+                if tracer:
+                    jax.block_until_ready(forest.visits)
+            playouts_per_tree += tasks * m
+            if merge_every > 0 and ((r + 1) % merge_every == 0
+                                    or r == len(schedule) - 1):
+                forest, state = sync_root_stats(forest, state, n_moves)
+                n_syncs += 1
+        with span("search_wait"):
+            jax.block_until_ready(forest.visits)
+        dt = time.perf_counter() - t0
 
-    if padded_members:
-        forest = jax.tree.map(lambda x: x[:E], forest)
-        if cfg.metrics:
-            metrics = jax.tree.map(lambda x: x[:E], metrics)
-    playouts = E * playouts_per_tree
-    summary = jax.device_get(forest_summary(forest, n_moves))
-    stats = {
-        "time_s": dt,
-        "n_trees": E,
-        "playouts": playouts,
-        "playouts_per_tree": playouts_per_tree,
-        "playouts_per_s": playouts / max(dt, 1e-9),
-        "rounds": len(schedule),
-        "grain": cfg.grain,
-        "n_syncs": n_syncs,
-        "sharded": mesh is not None,
-        "n_devices": (1 if mesh is None
-                      else int(np.prod(mesh.devices.shape))),
-        "mesh_shape": (None if mesh is None
-                       else dict(zip(mesh.axis_names,
-                                     (int(d) for d in mesh.devices.shape)))),
-        "padded_members": padded_members,
-        "tree_nodes": [int(n) for n in np.asarray(forest.n_nodes)],
-        "member_best_moves": summary["member_best_moves"].tolist(),
-        "member_root_values": summary["member_root_values"].tolist(),
-        "best_move_sum": int(summary["best_move_sum"]),
-        "best_move_vote": int(summary["best_move_vote"]),
-    }
-    if reused_nodes:
-        stats["reused_nodes"] = reused_nodes
-    if cfg.metrics:
-        from repro.obsv.search_metrics import summarize_metrics
-        stats["metrics"] = summarize_metrics(metrics)
+        with span("search_stats"):
+            if padded_members:
+                forest = jax.tree.map(lambda x: x[:E], forest)
+                if cfg.metrics:
+                    metrics = jax.tree.map(lambda x: x[:E], metrics)
+            playouts = E * playouts_per_tree
+            summary = jax.device_get(forest_summary(forest, n_moves))
+            stats = {
+                "time_s": dt,
+                "n_trees": E,
+                "playouts": playouts,
+                "playouts_per_tree": playouts_per_tree,
+                "playouts_per_s": playouts / max(dt, 1e-9),
+                "rounds": len(schedule),
+                "grain": cfg.grain,
+                "n_syncs": n_syncs,
+                "sharded": mesh is not None,
+                "n_devices": (1 if mesh is None
+                              else int(np.prod(mesh.devices.shape))),
+                "mesh_shape": (None if mesh is None
+                               else dict(zip(mesh.axis_names,
+                                             (int(d) for d in
+                                              mesh.devices.shape)))),
+                "padded_members": padded_members,
+                "tree_nodes": [int(n) for n in np.asarray(forest.n_nodes)],
+                "member_best_moves": summary["member_best_moves"].tolist(),
+                "member_root_values": summary["member_root_values"].tolist(),
+                "best_move_sum": int(summary["best_move_sum"]),
+                "best_move_vote": int(summary["best_move_vote"]),
+            }
+            if reused_nodes:
+                stats["reused_nodes"] = reused_nodes
+            if cfg.metrics:
+                from repro.obsv.search_metrics import summarize_metrics
+                stats["metrics"] = summarize_metrics(metrics)
     return forest, stats
 
 

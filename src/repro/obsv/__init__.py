@@ -5,9 +5,11 @@ per-round counters carried through the jitted search chunks as an optional
 accumulator — the search results stay bit-identical with metrics on or
 off, and the host reads one small pytree per chunk.
 
-Host plane (``trace`` / ``metrics``): a Chrome/Perfetto trace-event span
-recorder for scheduler events (admission, quanta, preemption, deadline
-expiry, device sync, jit compiles) plus a counter/gauge registry with JSON
+Host plane (``trace`` / ``metrics``): one span API, ``trace.span``, on the
+profiler's clock (always on; a ``jax.profiler.TraceAnnotation`` each) that
+also feeds a Chrome/Perfetto trace-event recorder for scheduler events
+(admission, quanta, preemption, deadline expiry, device sync, jit
+compiles) when one is attached, plus a counter/gauge registry with JSON
 snapshots and a Prometheus-style text exposition.
 
 ``profile`` closes the loop: it fits the measured per-round dispatch cost

@@ -18,8 +18,8 @@ Fig 9 overlay (``benchmarks/fig9_mapping.py``).
 
 Span vocabulary consumed here (recorded by ``gscpm_search(tracer=...)``
 and ``serve/games.TPFIFOGameEngine``): any ``X`` event whose ``args``
-carry ``rounds`` and ``iterations``; ``lane_iterations`` and ``workers``
-ride along for bookkeeping.
+carry ``rounds`` and ``iterations``; ``workers`` (and a quantum's
+``lane_iterations``) ride along for bookkeeping.
 """
 
 from __future__ import annotations

@@ -1,26 +1,33 @@
-"""Host-plane span recorder: Chrome/Perfetto trace-event JSON (DESIGN.md §15).
+"""Host-plane spans: one span API on the profiler's clock (DESIGN.md §15).
 
 The serving and search drivers are host-side loops dispatching jitted
-quanta; their time structure (admission waits, quantum dispatch, device
-sync, preemption churn, compile stalls) is exactly what the paper's
-profiling chapters measure. ``TraceRecorder`` records that structure as
-trace-event JSON — open ``chrome://tracing`` or https://ui.perfetto.dev
-and load the file.
+quanta; their time structure (rounds, quantum dispatch, device sync,
+admission waits, preemption churn, compile stalls) is exactly what the
+paper's profiling chapters measure.
 
-Event vocabulary (the ``ph`` field of the trace-event format):
+``span(name, recorder=None, **args)`` is the one way to mark it. Every span
+enters a ``jax.profiler.TraceAnnotation``: under a running profiler
+(``jax.profiler.start_trace``) it is an event of the profile's host plane,
+with ``args`` as its stats, on the same clock as the device ops of that
+profile; with no profiler running it costs about a microsecond, so the
+search's round loop keeps its spans always on. Given a ``TraceRecorder``,
+the span is also recorded there.
+
+``TraceRecorder`` writes Chrome/Perfetto trace-event JSON — open
+``chrome://tracing`` or https://ui.perfetto.dev and load the file — on its
+own clock: microseconds from the recorder's creation (``time.perf_counter``
+based, so spans compose with the drivers' own telemetry clocks). Event
+vocabulary (the ``ph`` field of the trace-event format):
 
 - ``X`` *complete* spans with a duration — quanta, rounds, device syncs
-  (``TraceRecorder.span`` context manager);
-- ``B``/``E`` nested begin/end pairs for open-ended phases;
+  (``span`` with a recorder, or ``TraceRecorder.span``);
 - ``i`` *instant* events — admission, preemption, retirement, deadline
   expiry, jit compiles;
 - ``C`` counter tracks — queue depth, active slots;
-- ``M`` metadata naming the process/thread tracks.
+- ``M`` metadata naming the process track.
 
-Timestamps are microseconds from the recorder's creation
-(``time.perf_counter`` based, so spans compose with the drivers' own
-telemetry clocks). Recording never raises into the traced code path: a
-``None`` recorder is the off switch and every driver hook guards on it.
+Recording never raises into the traced code path: a ``None`` recorder is
+the off switch and every driver hook guards on it.
 
 ``CompileWatch`` turns jit-cache growth into trace events: it snapshots
 ``fn._cache_size()`` for registered jitted callables and, on each
@@ -34,6 +41,34 @@ import contextlib
 import json
 import time
 from typing import Any, Callable
+
+import jax
+
+
+PHASES = ("X", "i", "C", "M")
+
+
+@contextlib.contextmanager
+def span(name: str, recorder: TraceRecorder | None = None, **args):
+    """A named host span: a profiler event always, a recorder event too
+    when ``recorder`` is given.
+
+    The block receives ``args`` and may update it (e.g. the rounds a
+    quantum actually ran); with a recorder, both its event and the
+    profiler's carry the values at exit.
+    """
+    with jax.profiler.TraceAnnotation(name, **args) as ann:
+        if recorder is None:
+            yield args
+            return
+        t0 = recorder.ts_us()
+        try:
+            yield args
+        finally:
+            if args:
+                ann.set_metadata(**args)
+            recorder.complete(name, t0, recorder.ts_us() - t0,
+                              args=args or None)
 
 
 class CompileWatch:
@@ -64,7 +99,6 @@ class TraceRecorder:
         self._t0 = clock()
         self.events: list[dict] = []
         self._watches: list[CompileWatch] = []
-        self._open: dict[int, list[str]] = {}   # tid -> begin-stack
         self.metadata("process_name", {"name": process_name})
 
     # -- clock ------------------------------------------------------------
@@ -83,20 +117,8 @@ class TraceRecorder:
     def metadata(self, name: str, args: dict, tid: int = 0):
         self._emit("M", name, ts=0.0, tid=tid, args=args)
 
-    def name_thread(self, tid: int, name: str):
-        self.metadata("thread_name", {"name": name}, tid=tid)
-
     def instant(self, name: str, args: dict | None = None, tid: int = 0):
         self._emit("i", name, tid=tid, s="t", args=args)
-
-    def begin(self, name: str, args: dict | None = None, tid: int = 0):
-        self._open.setdefault(tid, []).append(name)
-        self._emit("B", name, tid=tid, args=args)
-
-    def end(self, tid: int = 0, args: dict | None = None):
-        stack = self._open.get(tid, [])
-        name = stack.pop() if stack else "?"
-        self._emit("E", name, tid=tid, args=args)
 
     def complete(self, name: str, ts_us: float, dur_us: float,
                  args: dict | None = None, tid: int = 0):
@@ -106,18 +128,10 @@ class TraceRecorder:
     def counter(self, name: str, values: dict, tid: int = 0):
         self._emit("C", name, tid=tid, args=values)
 
-    @contextlib.contextmanager
-    def span(self, name: str, args: dict | None = None, tid: int = 0):
-        """Complete-event context: ``with tracer.span("quantum", {...}):``.
-
-        ``args`` may be mutated inside the block (e.g. to record how many
-        rounds actually ran) — the event is emitted at exit.
-        """
-        t0 = self.ts_us()
-        try:
-            yield args
-        finally:
-            self.complete(name, t0, self.ts_us() - t0, args=args, tid=tid)
+    def span(self, name: str, args: dict | None = None):
+        """``with tracer.span("quantum", {...}) as args:`` — the module's
+        ``span`` with this recorder attached."""
+        return span(name, self, **(args or {}))
 
     # -- compile counting -------------------------------------------------
     def watch_compiles(self, name: str, fn: Any) -> CompileWatch:
@@ -152,9 +166,9 @@ def validate_trace(obj: dict | str) -> int:
     """Structural check of a trace (dict or file path) -> event count.
 
     Raises ``ValueError`` on malformed traces: missing ``traceEvents``,
-    events without name/ph/ts, ``X`` events without ``dur``, or unbalanced
-    ``B``/``E`` pairs per (pid, tid) track. Used by the CI trace smoke and
-    by tests.
+    events without name/ph/ts, a phase outside the recorder's vocabulary
+    (``X``, ``i``, ``C``, ``M``), or ``X`` events without ``dur``. Used by
+    the CI trace smoke and by tests.
     """
     if isinstance(obj, str):
         with open(obj) as f:
@@ -162,22 +176,14 @@ def validate_trace(obj: dict | str) -> int:
     events = obj.get("traceEvents")
     if not isinstance(events, list) or not events:
         raise ValueError("trace has no traceEvents list")
-    depth: dict[tuple, int] = {}
     for i, ev in enumerate(events):
         for field in ("name", "ph", "ts"):
             if field not in ev:
                 raise ValueError(f"event {i} missing {field!r}: {ev}")
+        if ev["ph"] not in PHASES:
+            raise ValueError(f"event {i} has unknown phase {ev['ph']!r}: "
+                             f"{ev}")
         if ev["ph"] == "X" and "dur" not in ev:
             raise ValueError(f"complete event {i} missing dur: {ev}")
-        track = (ev.get("pid", 0), ev.get("tid", 0))
-        if ev["ph"] == "B":
-            depth[track] = depth.get(track, 0) + 1
-        elif ev["ph"] == "E":
-            depth[track] = depth.get(track, 0) - 1
-            if depth[track] < 0:
-                raise ValueError(f"unbalanced E at event {i} on {track}")
-    bad = {t: d for t, d in depth.items() if d != 0}
-    if bad:
-        raise ValueError(f"unclosed B spans: {bad}")
     json.dumps(events[: min(len(events), 64)])   # must be JSON-serializable
     return len(events)

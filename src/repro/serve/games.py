@@ -576,12 +576,12 @@ class TPFIFOGameEngine(TPFIFODriver):
                 raise InjectedFaultError(
                     f"injected dispatch failure: tick {self._ticks}, "
                     f"slot {self._flat_slot(slot_key)}, rid {t.req.rid}")
-        span_args = {"rid": t.req.rid, "game": st.cfg.game, "rounds": 0,
-                     "iterations": 0, "lane_iterations": 0,
-                     "workers": st.cfg.n_workers} if self.tracer else None
-        span = (self.tracer.span("quantum", span_args) if self.tracer
-                else contextlib.nullcontext())
-        with span:
+        span = (self.tracer.span("quantum", {
+            "rid": t.req.rid, "game": st.cfg.game, "rounds": 0,
+            "iterations": 0, "lane_iterations": 0,
+            "workers": st.cfg.n_workers}) if self.tracer
+            else contextlib.nullcontext())
+        with span as span_args:
             for _ in range(m):
                 if st.round_idx >= len(st.schedule):
                     break

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import re
 from unittest import mock
 
 import jax
@@ -44,6 +45,7 @@ from repro.obsv import (
     summarize_metrics,
     validate_trace,
 )
+from repro.obsv.trace import span
 from repro.serve.games import GameRequest, TPFIFOGameEngine
 from repro.serve.tpfifo import QueueStats, Ticket
 
@@ -188,17 +190,16 @@ def test_merge_metrics_sum_vs_max_fields():
 # ------------------------------------------------------------------ tracer ----
 def test_trace_recorder_structure_and_validation(tmp_path):
     tr = TraceRecorder(process_name="t")
-    tr.name_thread(1, "worker")
     tr.instant("evt", {"k": 1})
-    tr.begin("outer", tid=1)
-    tr.end(tid=1)
-    with tr.span("quantum", {"rounds": 2}):
-        pass
+    with tr.span("quantum", {"rounds": 0}) as args:
+        args["rounds"] += 2             # the event carries the exit value
     tr.counter("queue", {"depth": 3})
     d = tr.to_dict()
     assert d["displayTimeUnit"] == "ms"
+    assert [e["ph"] for e in d["traceEvents"]] == ["M", "i", "X", "C"]
+    assert d["traceEvents"][2]["args"] == {"rounds": 2}
     n = validate_trace(d)
-    assert n == len(d["traceEvents"]) >= 6
+    assert n == len(d["traceEvents"]) == 4
     path = tr.save(str(tmp_path / "t.json"))
     assert validate_trace(path) == n
     with open(path) as f:
@@ -212,10 +213,32 @@ def test_validate_trace_rejects_malformed():
         validate_trace({"traceEvents": [{"ph": "i", "ts": 0}]})
     with pytest.raises(ValueError, match="dur"):
         validate_trace({"traceEvents": [{"name": "x", "ph": "X", "ts": 0}]})
-    with pytest.raises(ValueError, match="unclosed"):
+    # begin/end pairs are not the recorder's vocabulary: spans are X events
+    with pytest.raises(ValueError, match="unknown phase"):
         validate_trace({"traceEvents": [{"name": "b", "ph": "B", "ts": 0}]})
-    with pytest.raises(ValueError, match="unbalanced"):
+    with pytest.raises(ValueError, match="unknown phase"):
         validate_trace({"traceEvents": [{"name": "e", "ph": "E", "ts": 0}]})
+
+
+def test_span_without_recorder_records_nothing():
+    tr = TraceRecorder()
+    before = list(tr.events)
+    with span("gscpm_round", round=0, m=4) as args:
+        assert args == {"round": 0, "m": 4}
+    with span("search_wait"):
+        pass
+    assert tr.events == before
+
+
+def test_span_with_recorder_records_one_complete_event():
+    tr = TraceRecorder()
+    n = len(tr.events)
+    with span("gscpm_round", tr, round=3, m=256, tasks=244):
+        pass
+    (ev,) = tr.events[n:]
+    assert ev["name"] == "gscpm_round" and ev["ph"] == "X"
+    assert ev["dur"] >= 0.0
+    assert ev["args"] == {"round": 3, "m": 256, "tasks": 244}
 
 
 def test_compile_watch_counts_jit_cache_growth():
@@ -475,3 +498,115 @@ def test_gscpm_search_tracer_records_fittable_rounds():
     prof = fit_dispatch_profile(tr, n_workers=cfg.n_workers)
     assert prof["t_iter_s"] >= 0.0
     assert validate_trace(tr.to_dict()) == len(tr.events)
+
+
+# ----------------------------------------- phase scopes and program spans ----
+PHASE_SCOPES = ("descent", "expand", "leaf_eval", "backup")
+SEARCH_SPANS = ("gscpm_search", "search_init", "gscpm_round", "search_wait",
+                "search_stats")
+
+
+def _op_name_paths(hlo_text: str) -> list[list[str]]:
+    return [p.split("/") for p in re.findall(r'op_name="([^"]*)"', hlo_text)]
+
+
+def _compiled_text(program: str) -> str:
+    from repro.core.gscpm import fold_task_keys
+    from repro.core.root_parallel import (fold_member_task_keys,
+                                          init_sync_state, sync_root_stats)
+    from repro.core.tree import init_forest
+
+    cfg = cfg_for("hex", vl_rounds=2 if program == "run_chunk_vl2" else 1)
+    game, W, E = cfg.game_obj, cfg.n_workers, 2
+    task_ids = jnp.arange(W, dtype=jnp.int32)
+    m, cp = jnp.int32(2), jnp.float32(1.0)
+    if program == "sync_root_stats":
+        forest = init_forest(E, cfg.tree_cap, game.n_actions, 1)
+        return sync_root_stats.lower(
+            forest, init_sync_state(E, game.n_actions),
+            game.n_actions).compile().as_text()
+    if program == "run_chunk_forest":
+        forest = init_forest(E, cfg.tree_cap, game.n_actions, 1)
+        keys = fold_member_task_keys(
+            fold_task_keys(jax.random.key(0), jnp.arange(E)), task_ids)
+        boards = jnp.tile(game.init_board()[None, :], (E, 1))
+        return run_chunk_forest.lower(
+            forest, boards, cfg, keys, jnp.ones((E, W), bool), m,
+            cp).compile().as_text()
+    tree = init_tree(cfg.tree_cap, game.n_actions, 1)
+    keys = fold_task_keys(jax.random.key(0), task_ids)
+    return run_chunk.lower(tree, game.init_board(), cfg, keys,
+                           jnp.ones((W,), bool), m, cp).compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["run_chunk", "run_chunk_vl2",
+                                     "run_chunk_forest", "sync_root_stats"])
+def test_phase_scopes_in_compiled_programs(program):
+    """The named phases reach the compiled program's op_name metadata,
+    where a device trace can attribute each op; the forest's merge too."""
+    paths = _op_name_paths(_compiled_text(program))
+    parts = {p for path in paths for p in path}
+    want = {"merge"} if program == "sync_root_stats" else set(PHASE_SCOPES)
+    assert want <= parts
+    if program == "run_chunk_vl2":
+        # the virtual-loss add between selection rounds is backup work
+        assert any("descent" in p and "backup" in p[p.index("descent"):]
+                   for p in paths)
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; return its host-plane events
+    named by the search spans as (start_ns, name, stats dict)."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    events = sorted(
+        (e.start_ns, e.name, dict(e.stats))
+        for plane in pd.planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name in SEARCH_SPANS)
+    return out, events
+
+
+@pytest.mark.parametrize("driver", ["gscpm_search", "gscpm_search_batch"])
+def test_profiler_trace_holds_the_search_spans(tmp_path, driver):
+    cfg = cfg_for("hex", n_playouts=48, n_tasks=12)
+    board = cfg.game_obj.init_board()
+    schedule = scheduler.make_schedule(cfg.n_playouts, cfg.n_tasks,
+                                       cfg.n_workers, cfg.scheduler)
+    if driver == "gscpm_search":
+        run = lambda: gscpm_search(board, 1, cfg, jax.random.key(3))  # noqa
+        trees = 1
+    else:
+        run = lambda: gscpm_search_batch(  # noqa: E731
+            board, 1, cfg, jax.random.key(3), n_trees=2, shard="off")
+        trees = 2
+    run()                                  # compile outside the trace
+    _, events = _profile(tmp_path, run)
+    names = [n for _, n, _ in events]
+    for name in ("gscpm_search", "search_init", "search_wait",
+                 "search_stats"):
+        assert names.count(name) == 1, name
+    rounds = [st for _, n, st in events if n == "gscpm_round"]
+    assert len(rounds) == len(schedule) == 3
+    for r, (st, rnd) in enumerate(zip(rounds, schedule)):
+        assert st["round"] == r and st["m"] == rnd.m
+        assert st["tasks"] == trees * int(rnd.active.sum())
+    # the round spans lie inside the search span, after its init
+    start = {n: s for s, n, _ in events}
+    assert start["gscpm_search"] <= start["search_init"] < min(
+        s for s, n, _ in events if n == "gscpm_round")
+
+
+def test_search_bit_identical_with_the_profiler_running(tmp_path):
+    cfg = cfg_for("hex")
+    board = cfg.game_obj.init_board()
+    plain, s_plain = gscpm_search(board, 1, cfg, jax.random.key(5))
+    (traced, s_traced), _ = _profile(
+        tmp_path, lambda: gscpm_search(board, 1, cfg, jax.random.key(5)))
+    assert trees_equal(plain, traced)
+    assert s_plain["best_move"] == s_traced["best_move"]
