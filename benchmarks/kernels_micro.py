@@ -83,7 +83,7 @@ def run(seed: int = 0) -> dict:
     # flood fill vs O(log n) pointer doubling), scalar-vmap vs batched, and
     # the fused playout stage. The interpret-mode Pallas kernel run is
     # validation-only; the timed paths are the real dispatch
-    # (pointer-doubling Pallas on TPU, batched flood fill elsewhere) and
+    # (flood-fill Pallas on TPU, batched flood fill elsewhere) and
     # the jitted alternatives it was chosen against.
     hw = {}
     for (size, W) in [(9, 16), (11, 16), (11, 128)]:

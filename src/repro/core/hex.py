@@ -7,20 +7,21 @@ index of an empty cell.
 
 Hardware adaptation (DESIGN.md §2/§9/§12): the paper uses a disjoint-set
 (union-find) structure for connectivity. Union-find is pointer-chasing and
-hostile to vector hardware, so we use two vectorizable equivalents:
+hostile to vector hardware, so we use vectorizable equivalents:
 
 - a frontier flood-fill to a fixpoint (`lax.while_loop` over neighbor
   dilation) — the scalar oracle (`connected`/`winner`), O(board diameter)
   steps, tested against a python union-find oracle in tests/test_hex.py;
-  its batched gather-free twin (`winner_flood_batch`) is the CPU/GPU
-  winner dispatch;
+  its batched gather-free twin (`winner_flood_batch`) is the off-TPU
+  winner dispatch, and the `kernels/hex_winner.py` Pallas kernel runs the
+  same six-shift dilation on TPU for a fixed, proven n_cells - 1 steps;
 - **batched pointer-doubling** connected-component labeling
   (`cc_labels_batch` / `connected_batch`) — the Shiloach–Vishkin/FastSV
   hook-and-jump scheme over a whole (W, n_cells) tile at once, converging
-  in O(log n_cells) rounds with ONE convergence loop for all W lanes: the
-  vector-hardware formulation the `kernels/hex_winner.py` Pallas kernel
-  compiles on TPU (bit-exact vs the flood-fill oracle,
-  tests/test_hex_batch.py).
+  in O(log n_cells) rounds with ONE convergence loop for all W lanes. The
+  TPU kernel used it until a chip measurement (1,991 us per 244-board
+  11x11 call on a v5e); it stays as an independent oracle, bit-exact vs
+  the flood fill (tests/test_hex_batch.py, tests/test_kernels.py).
 
 `winner_batch`/`playout_batch` pick the right body per backend through
 ``kernels.ops.hex_winner`` (DESIGN.md §12).
@@ -214,10 +215,9 @@ def doubling_rounds(n_cells: int) -> int:
     converges well inside this bound — empirically <= 7 rounds on random
     AND adversarial snake/comb/solid boards up to 25x25, against caps of
     9-12 (tests/test_hex_batch.py pins convergence at exactly this budget,
-    adversarial shapes included). The Pallas kernel runs exactly this many
-    rounds with no runtime convergence check, so DO NOT tighten this
-    budget without re-running those tests at the larger sizes; the jnp
-    path early-exits at the batch fixpoint.
+    adversarial shapes included). It is an observed bound, so only the
+    fixed-round variant of ``cc_labels_batch`` (``rounds=``) uses it, in
+    those tests; the default path early-exits at the batch fixpoint.
     """
     return int(math.ceil(math.log2(max(2, n_cells)))) + 2
 
@@ -244,8 +244,8 @@ def cc_labels_batch(stones: jnp.ndarray, spec: HexSpec,
     the exact component-min labeling (hook fixpoint => locally constant =>
     min per component). ``rounds=None`` runs ONE `lax.while_loop` to the
     fixpoint of the whole batch (early exit, typical 4-6 rounds);
-    ``rounds=k`` runs a fixed `fori_loop` (the kernel-shaped variant the
-    fixed-step-count test exercises).
+    ``rounds=k`` runs a fixed `fori_loop` (the variant the fixed-round
+    tests exercise).
     """
     nbr, *_ = _static_tables(spec.size)
     nbr = jnp.asarray(nbr)                     # (n, 6), sentinel == n
@@ -313,11 +313,12 @@ def winner_flood_batch(boards: jnp.ndarray, spec: HexSpec) -> jnp.ndarray:
     Same filled-board contract as `winner`. One reach set for all W lanes,
     dilated with the six static shifts of ``_shift_tables`` per step and
     ONE convergence check for the whole batch — O(board diameter) steps of
-    very cheap boolean work. On scalar-ish hardware (CPU) this beats the
-    O(log n) pointer-doubling solve, whose per-round gathers cost more
-    than a handful of extra boolean dilations; ``kernels.ops.hex_winner``
-    therefore dispatches HERE off-TPU and to the pointer-doubling Pallas
-    kernel on TPU (DESIGN.md §12; benchmarks/kernels_micro.py times both).
+    very cheap boolean work. ``kernels.ops.hex_winner`` dispatches HERE
+    off-TPU; on TPU the Pallas kernel (`kernels/hex_winner.py`) runs the
+    same dilation for a fixed n_cells - 1 steps, with no convergence
+    check. Both beat the O(log n) pointer-doubling solve: its rounds cost
+    gathers or one-hot tiles, where a dilation step is a few shifted ANDs
+    (DESIGN.md §12).
     """
     offs, masks = _shift_tables(spec.size)
     _, top, bottom, *_ = _static_tables(spec.size)
@@ -342,8 +343,8 @@ def winner_batch(boards: jnp.ndarray, spec: HexSpec) -> jnp.ndarray:
     """Batched `winner`: (W, n_cells) FILLED boards -> (W,) int8 in {1, 2}.
 
     Same contract as `winner` (boards must be filled). Dispatches through
-    ``kernels.ops.hex_winner`` — the compiled Pallas pointer-doubling
-    kernel on TPU, the jitted batched flood fill elsewhere (DESIGN.md §12).
+    ``kernels.ops.hex_winner`` — the compiled Pallas flood-fill kernel on
+    TPU, the jitted batched flood fill elsewhere (DESIGN.md §12).
     """
     from repro.kernels import ops  # function-level: kernels ref imports hex
 
@@ -443,8 +444,7 @@ class HexGame(NamedTuple):
     pre-seam Hex-coupled search ran — bit-identical trees, pinned by
     tests/test_game_protocol.py. Hex never draws (Hex theorem), a game ends
     only when the board fills, and ``winner_batch`` keeps the per-backend
-    pointer-doubling/flood dispatch of ``kernels.ops.hex_winner``
-    (DESIGN.md §12).
+    dispatch of ``kernels.ops.hex_winner`` (DESIGN.md §12).
     """
 
     size: int

@@ -78,15 +78,12 @@ def hex_winner(boards, size: int, interpret: bool | None = None):
 
     boards: (W, size*size) FILLED boards; returns (W,) int8 winners.
     interpret=None (the default) picks the fast path per backend exactly
-    like ``uct_select``: the compiled pointer-doubling Pallas kernel on
-    TPU; elsewhere the jitted batched flood fill — on scalar-ish hardware
-    a handful of extra boolean dilation steps are cheaper than the
-    pointer-doubling round's gathers, so the O(log n) formulation is the
-    *vector-hardware* fast path, not a universal one (DESIGN.md §12,
-    measured in benchmarks/kernels_micro.py). Pass interpret=True to force
-    the interpret-mode kernel for validation (never a timing path); the
-    pointer-doubling jnp reference stays in ``kernels.ref`` as the
-    kernel-semantics oracle.
+    like ``uct_select``: on TPU the compiled Pallas kernel, a roll-dilation
+    flood fill with a fixed ``n_cells - 1`` steps; elsewhere the jitted
+    batched flood fill, the same dilation with a convergence check
+    (DESIGN.md §12). Pass interpret=True to force the interpret-mode kernel
+    for validation (never a timing path); the pointer-doubling jnp
+    reference stays in ``kernels.ref`` as an independent oracle.
     """
     if interpret is None and jax.default_backend() != "tpu":
         return _jitted_flood_hex_winner(boards, size)
@@ -105,12 +102,12 @@ def gomoku_winner(boards, size: int, interpret: bool | None = None):
     ``hex_winner`` (DESIGN.md §13).
 
     boards: (W, size*size) TERMINAL boards; returns (W,) int8 in
-    {0 draw, 1, 2}. Unlike Hex — whose connectivity solve has two
-    formulations with backend-dependent winners (pointer doubling vs flood
-    fill) — the five-in-a-row test is four static-roll window scans that
-    lower to plain vector shifts/ANDs on every backend, so a single jitted
-    jnp body serves TPU and CPU alike. A dedicated Pallas kernel slot stays
-    open in ROADMAP.md; ``interpret`` is accepted for signature symmetry.
+    {0 draw, 1, 2}. Unlike Hex — whose connectivity solve has a Pallas
+    body on TPU and a jnp loop elsewhere — the five-in-a-row test is four
+    static-roll window scans that lower to plain vector shifts/ANDs on
+    every backend, so a single jitted jnp body serves TPU and CPU alike. A
+    dedicated Pallas kernel slot stays open in ROADMAP.md; ``interpret`` is
+    accepted for signature symmetry.
     """
     del interpret  # no Pallas body yet — one jnp path on all backends
     return _jitted_gomoku_winner(boards, size)

@@ -52,9 +52,9 @@ def hex_winner(boards: jnp.ndarray, size: int) -> jnp.ndarray:
     """(W, size*size) FILLED boards -> (W,) int8 winners in {1, 2}.
 
     Same filled-board contract as the kernel (`repro.core.hex.winner`).
-    The batched pointer-doubling solve in `repro.core.hex` IS the jnp
-    reference semantics: one connectivity check for BLACK decides every
-    lane (the Hex theorem).
+    The batched pointer-doubling solve in `repro.core.hex`, a different
+    algorithm from the kernel's flood fill, so an independent oracle: one
+    connectivity check for BLACK decides every lane (the Hex theorem).
     """
     from repro.core import hex as hx
     black = hx.connected_batch(boards, hx.BLACK, hx.HexSpec(size))

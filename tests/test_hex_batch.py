@@ -6,8 +6,8 @@ and the fused `playout_batch` must be BIT-identical to the vmapped scalar
 oracles (`connected` / `winner` / `random_fill` / `playout`) under the same
 RNG schedule — across board sizes, batch widths, partial and filled boards,
 and under a further vmap over the forest axis. Pointer doubling must also
-converge within the fixed ceil(log2(n_cells)) + 2 round budget the Pallas
-kernel hard-codes.
+converge within the fixed ceil(log2(n_cells)) + 2 round budget of its
+fixed-round variant.
 """
 
 from __future__ import annotations
@@ -95,10 +95,11 @@ def adversarial_stones(size: int) -> np.ndarray:
 
 @pytest.mark.parametrize("size", [11, 17, 25])
 def test_fixed_round_budget_adversarial_boards(size):
-    """The kernel's fixed round budget has NO runtime convergence check, so
-    it must reach the exact CC fixpoint on the worst component shapes too —
-    snake/comb/solid boards at sizes beyond the play configs (empirically
-    <= 7 rounds vs caps of 9-12; do not tighten the budget without this)."""
+    """The fixed-round labeling has NO runtime convergence check, so its
+    budget must reach the exact CC fixpoint on the worst component shapes
+    too — snake/comb/solid boards at sizes beyond the play configs
+    (empirically <= 7 rounds vs caps of 9-12; do not tighten the budget
+    without this)."""
     spec = hx.HexSpec(size)
     cap = hx.doubling_rounds(size * size)
     stones = jnp.asarray(adversarial_stones(size))
@@ -111,8 +112,8 @@ def test_fixed_round_budget_adversarial_boards(size):
 @given(seed=st.integers(0, 2**31 - 1), size=st.sampled_from(list(SIZES)),
        W=st.sampled_from(list(WIDTHS)))
 def test_fixed_doubling_round_budget(seed, size, W):
-    """Pointer doubling reaches the exact CC fixpoint within the kernel's
-    fixed ceil(log2(n_cells)) + 2 rounds — on random partial boards AND the
+    """Pointer doubling reaches the exact CC fixpoint within the fixed
+    ceil(log2(n_cells)) + 2 rounds of ``doubling_rounds`` — on random partial boards AND the
     adversarial all-one-color board (worst-case component diameter)."""
     spec = hx.HexSpec(size)
     n = size * size

@@ -99,7 +99,7 @@ def test_uct_select_dispatch_agrees_with_kernel():
 
 @pytest.mark.parametrize("size,W", [(5, 1), (5, 7), (9, 16), (11, 16)])
 def test_hex_winner_kernel_vs_oracle(size, W):
-    """Interpret-mode pointer-doubling Pallas kernel (validation-only path)
+    """Interpret-mode roll-dilation Pallas kernel (validation-only path)
     == the jnp pointer-doubling reference == the scalar flood-fill winner,
     on filled boards (the kernel's contract domain)."""
     from repro.core import hex as hx
@@ -118,8 +118,9 @@ def test_hex_winner_kernel_vs_oracle(size, W):
 def test_hex_winner_dispatch_agrees_with_kernel():
     """The auto dispatch the playout phase hits (compiled Pallas on TPU,
     jitted batched flood fill elsewhere) returns the same winners as the
-    interpret-mode pointer-doubling kernel — independent implementations
-    on every backend, so non-vacuous on the CPU CI host too."""
+    interpret-mode roll-dilation kernel — separate implementations on
+    every backend (a Pallas body against a jnp while_loop), so non-vacuous
+    on the CPU CI host too."""
     from repro.core import hex as hx
     size, W = 9, 12
     spec = hx.HexSpec(size)
@@ -129,6 +130,121 @@ def test_hex_winner_dispatch_agrees_with_kernel():
     got = ops.hex_winner(filled, size)
     kernel = ops.hex_winner(filled, size, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(kernel))
+
+
+HEX_DELTAS = ((-1, 0), (-1, 1), (0, -1), (0, 1), (1, -1), (1, 0))
+
+
+def _serpentine(size: int, reach_bottom: bool = True) -> np.ndarray:
+    """Black enters at (0, 0), runs along rows 1, 3, 5, ... joined at
+    alternate ends, and ends on the bottom row: one path with white walls
+    between its rows, so the flood advances one cell per step along it.
+    Without ``reach_bottom`` the bottom row is all white, and the path
+    stops one cell short of it."""
+    b = np.full((size, size), 2, np.int8)
+    b[0, 0] = 1
+    for i, r in enumerate(range(1, size, 2)):
+        b[r] = 1
+        if r + 1 < size:
+            b[r + 1, size - 1 if i % 2 == 0 else 0] = 1
+    if not reach_bottom:
+        b[size - 1] = 2
+    return b
+
+
+def _comb(size: int) -> np.ndarray:
+    """Black enters at (0, 0) onto a spine along row 1, with dead-end teeth
+    down every other column to one row short of the bottom; only the far
+    tooth touches the bottom row (odd sizes)."""
+    b = np.full((size, size), 2, np.int8)
+    b[0, 0] = 1
+    b[1] = 1
+    b[1:size - 1, ::2] = 1
+    b[size - 1, size - 1] = 1
+    return b
+
+
+def _flood_steps(board: np.ndarray) -> int:
+    """Dilation steps until black's reach from the top row stops growing
+    (breadth-first distances on the Hex neighborhood)."""
+    size = board.shape[0]
+    dist = np.full(board.shape, -1)
+    dist[0, board[0] == 1] = 0
+    frontier = [(0, c) for c in range(size) if board[0, c] == 1]
+    while frontier:
+        nxt = []
+        for r, c in frontier:
+            for dr, dc in HEX_DELTAS:
+                rr, cc = r + dr, c + dc
+                if (0 <= rr < size and 0 <= cc < size and board[rr, cc] == 1
+                        and dist[rr, cc] < 0):
+                    dist[rr, cc] = dist[r, c] + 1
+                    nxt.append((rr, cc))
+        frontier = nxt
+    return int(dist.max())
+
+
+def _long_path_batch(board: np.ndarray) -> np.ndarray:
+    """The board, its half turn (the path entered from the bottom), and its
+    transpose with colors swapped (the same path as white's, left to
+    right, so the other color wins), flattened: the half turn and the
+    transpose keep the Hex neighborhood."""
+    swapped = np.where(board.T == 1, 2, 1).astype(np.int8)
+    return np.stack([board, board[::-1, ::-1], swapped]).reshape(3, -1)
+
+
+def _assert_hex_winners_agree(filled: np.ndarray, size: int) -> np.ndarray:
+    """Interpret-mode kernel == scalar flood fill == pointer doubling."""
+    from repro.core import hex as hx
+    spec = hx.HexSpec(size)
+    boards = jnp.asarray(filled)
+    got = np.asarray(ops.hex_winner(boards, size, interpret=True))
+    scalar = np.asarray(jax.vmap(lambda b: hx.winner(b, spec))(boards))
+    doubling = np.asarray(ref.hex_winner(boards, size))
+    np.testing.assert_array_equal(got, scalar)
+    np.testing.assert_array_equal(got, doubling)
+    return got
+
+
+@pytest.mark.parametrize("case,size,black_wins,min_steps", [
+    ("serpentine", 11, True, 50),
+    ("serpentine", 13, True, 70),
+    ("near_miss", 11, False, 50),
+    ("near_miss", 13, False, 70),
+    ("comb", 11, True, 18),
+    ("comb", 13, True, 22),
+])
+def test_hex_winner_kernel_long_paths(case, size, black_wins, min_steps):
+    """Boards whose fixpoint takes many dilation steps: the kernel's fixed
+    n_cells - 1 steps must reach it (a step bound that falls short reads a
+    connected black path as white's win)."""
+    board = {"serpentine": _serpentine,
+             "near_miss": lambda s: _serpentine(s, reach_bottom=False),
+             "comb": _comb}[case](size)
+    assert _flood_steps(board) >= min_steps
+    got = _assert_hex_winners_agree(_long_path_batch(board), size)
+    w = 1 if black_wins else 2
+    assert got.tolist() == [w, w, 3 - w]
+
+
+@pytest.mark.parametrize("W", [7, 244, 1030])
+def test_hex_winner_kernel_batch_shapes(W):
+    """W not a multiple of 8 (padded rows), the paper's 244 lanes in one
+    block, and a W above the block's row cap (a grid of several blocks),
+    with the long-path boards at the first and the last lanes."""
+    from repro.core import hex as hx
+    size = 11
+    spec = hx.HexSpec(size)
+    keys = jax.random.split(jax.random.fold_in(KEY, 1000 + W), W)
+    filled = np.array(hx.random_fill_batch(
+        jnp.zeros((W, spec.n_cells), jnp.int8), 1, keys, spec))
+    long_paths = np.concatenate([_long_path_batch(_serpentine(size)),
+                                 _long_path_batch(_comb(size))])
+    k = min(W, len(long_paths))
+    filled[:k] = long_paths[:k]
+    filled[W - k:] = long_paths[:k]
+    got = _assert_hex_winners_agree(filled, size)
+    assert set(got.tolist()) == {1, 2}
 
 
 @settings(max_examples=20, deadline=None)
