@@ -22,9 +22,11 @@ three phases, each through the entry points a user calls:
    among them. Every request must be answered, and one served answer must
    equal the same search run directly, bit for bit.
 
-``--four-chips`` runs only ``gscpm_search_batch`` with 8 trees, sharded
-over 4 chips and unsharded on one, in this one process; root statistics
-and both merged moves must be equal.
+``--four-chips`` runs only ``gscpm_search_batch`` at the settings of the
+benchmark's ``hex11-forest8`` (8 trees of 124,928 playouts at ``PAPER``'s
+widths, tree cap 2^17, root statistics merged after each of the 2 rounds),
+sharded over 4 chips and unsharded on one, in this one process; every
+tree array and both merged moves must be equal.
 
 Each phase prints its wall time and the device's peak memory. The last
 line of standard output is one JSON object naming the device; it is
@@ -56,7 +58,13 @@ HOST_GRAIN = 16       # iterations of the search checked against the host
 HOST_TREE_CAP = 1 << 16
 SERVE_ROUNDS = 2      # schedule rounds per served request
 SERVE_GRAIN = 64      # iterations per round (m) of a served request
+# the forest of bench/configs/hex11-forest8.json: 488 tasks of grain 256
+# (2 rounds) per tree, a merge after every round
 FOREST_TREES = 8
+FOREST_PLAYOUTS = 124_928
+FOREST_TASKS = 488
+FOREST_TREE_CAP = 1 << 17
+FOREST_MERGE_EVERY = 1
 
 
 class SmokeFailure(RuntimeError):
@@ -316,51 +324,53 @@ def serving_phase(cfg, seed: int, rounds: int = SERVE_ROUNDS,
 
 
 def forest_phase(cfg, seed: int, n_trees: int = FOREST_TREES,
-                 rounds: int = SERVE_ROUNDS, grain: int = SERVE_GRAIN,
+                 n_playouts: int = FOREST_PLAYOUTS,
+                 n_tasks: int = FOREST_TASKS,
+                 tree_cap: int = FOREST_TREE_CAP,
+                 merge_every: int = FOREST_MERGE_EVERY,
                  n_devices: int = 4) -> None:
     """``gscpm_search_batch`` sharded over ``n_devices`` chips against the
-    same forest on one chip."""
+    same forest on one chip, at ``cfg``'s widths with the forest's budget,
+    tree cap and merges: every tree array must be equal."""
     from repro.core.root_parallel import gscpm_search_batch
-    from repro.core.tree import root_move_stats
 
     if len(jax.devices()) != n_devices:
         raise SmokeFailure(f"--four-chips needs {n_devices} devices, JAX "
                            f"sees {len(jax.devices())}")
-    W = cfg.n_workers
-    run = dataclasses.replace(cfg, n_playouts=rounds * W * grain,
-                              n_tasks=rounds * W)
+    run = dataclasses.replace(cfg, n_playouts=n_playouts, n_tasks=n_tasks,
+                              tree_cap=tree_cap)
     board = cfg.game_obj.init_board()
     key = jax.random.key(seed)
-    n_moves = cfg.game_obj.n_actions
-    move_stats = jax.jit(jax.vmap(lambda t: root_move_stats(t, n_moves)))
 
     out = {}
     for shard in ("require", "off"):
         t = time.perf_counter()
         forest, st = gscpm_search_batch(board, 1, run, key, n_trees=n_trees,
-                                        shard=shard)
+                                        merge_every=merge_every, shard=shard)
         wall = time.perf_counter() - t
         homes = {s.device for s in forest.visits.addressable_shards}
-        out[shard] = (jax.device_get(move_stats(forest)), st)
+        out[shard] = (jax.device_get(forest), st)
         del forest
         log(f"  shard={shard}: {n_trees} trees x {st['playouts_per_tree']} "
-            f"playouts on {len(homes)} device(s) {sorted(d.id for d in homes)}"
-            f", {wall:.2f}s wall (compile included), best move "
-            f"sum {st['best_move_sum']} vote {st['best_move_vote']}")
+            f"playouts, {st['n_syncs']} merges, on {len(homes)} device(s) "
+            f"{sorted(d.id for d in homes)}, {wall:.2f}s wall (compile "
+            f"included), best move sum {st['best_move_sum']} vote "
+            f"{st['best_move_vote']}")
         if shard == "require" and (len(homes) != n_devices
                                    or st["n_devices"] != n_devices):
             raise SmokeFailure(f"sharded forest sits on {len(homes)} "
                                f"device(s), not {n_devices}")
-    (v_s, w_s), st_s = out["require"]
-    (v_1, w_1), st_1 = out["off"]
-    same = (np.array_equal(v_s, v_1) and np.array_equal(w_s, w_1)
-            and st_s["best_move_sum"] == st_1["best_move_sum"]
-            and st_s["best_move_vote"] == st_1["best_move_vote"]
-            and st_s["member_best_moves"] == st_1["member_best_moves"])
-    log(f"  sharded vs one chip: {'bit-identical' if same else 'DIFFERENT'}")
-    if not same:
-        raise SmokeFailure("the sharded forest differs from the one-chip "
-                           "forest")
+    f_s, st_s = out["require"]
+    f_1, st_1 = out["off"]
+    differ = [k for k, a, b in zip(f_s._fields, f_s, f_1)
+              if not np.array_equal(a, b)]
+    differ += [k for k in ("n_syncs", "best_move_sum", "best_move_vote",
+                           "member_best_moves") if st_s[k] != st_1[k]]
+    log(f"  sharded vs one chip: "
+        f"{'bit-identical' if not differ else f'DIFFERENT in {differ}'}")
+    if differ or (merge_every and not st_s["n_syncs"]):
+        raise SmokeFailure(f"the sharded forest differs from the one-chip "
+                           f"forest ({differ}) or never merged")
 
 
 # -------------------------------------------------------------------- main ----

@@ -163,6 +163,50 @@ def pad_forest_members(forest: Tree, boards: jnp.ndarray, n_padded: int,
     return forest, boards
 
 
+@functools.partial(jax.jit, static_argnames=("n_trees", "cap",
+                                             "max_children", "sharding"))
+def _init_forest_placed(root_to_move, *, n_trees, cap, max_children,
+                        sharding):
+    """``init_forest`` as one program whose output carries ``sharding``:
+    each device builds only the members it holds."""
+    forest = init_forest(n_trees, cap, max_children, root_to_move)
+    if sharding is None:
+        return forest
+    return jax.lax.with_sharding_constraint(forest, sharding)
+
+
+def place_forest(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
+                 key: jax.Array, mesh=None, forest: Tree | None = None):
+    """``(forest, boards, member_keys)`` with each member on the device that
+    runs it.
+
+    ``boards`` is (E, n_cells). Without ``mesh`` nothing is sharded. With
+    one, the member axis is padded to a multiple of the device count
+    (``ensemble_sharding``) and sharded over it. A cold forest is built by
+    one program whose output is already sharded, so no device ever holds
+    the whole forest and nothing is scattered afterwards; a warm
+    ``forest`` (E members) is padded and moved. Member keys fold before
+    placement, so a member's stream is the same whatever the padding.
+    """
+    E = boards.shape[0]
+    sharding, Ep = (None, E) if mesh is None else ensemble_sharding(E, mesh)
+    if forest is None:
+        tm = np.broadcast_to(np.asarray(to_move, np.int32), (E,))
+        tm = np.concatenate([tm, np.full(Ep - E, tm[0], np.int32)])
+        forest = _init_forest_placed(
+            jnp.asarray(tm), n_trees=Ep, cap=cfg.tree_cap,
+            max_children=cfg.game_obj.n_actions, sharding=sharding)
+        boards = jnp.concatenate([boards, jnp.tile(boards[:1], (Ep - E, 1))])
+    else:
+        forest, boards = pad_forest_members(forest, boards, Ep, cfg, to_move)
+    member_keys = fold_task_keys(key, jnp.arange(Ep, dtype=jnp.int32))
+    if sharding is not None:
+        # a cold forest already sits there: placing it again moves nothing
+        forest, boards, member_keys = jax.device_put(
+            (forest, boards, member_keys), sharding)
+    return forest, boards, member_keys
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "mesh"),
                    donate_argnums=(0,))
 def _sharded_chunk(forest, boards, task_keys, active, m, cp, *, cfg, mesh):
@@ -350,7 +394,8 @@ def init_sync_state(n_trees: int, n_moves: int) -> RootSyncState:
     return RootSyncState(visits=z, wins=z, root_visits=z1, root_wins=z1)
 
 
-@functools.partial(jax.jit, static_argnames=("n_moves",))
+@functools.partial(jax.jit, static_argnames=("n_moves",),
+                   donate_argnums=(0,))
 @jax.named_scope("merge")
 def sync_root_stats(forest: Tree, state: RootSyncState, n_moves: int
                     ) -> tuple[Tree, RootSyncState]:
@@ -360,6 +405,12 @@ def sync_root_stats(forest: Tree, state: RootSyncState, n_moves: int
     ``own_e(a) + Σ_{e'≠e} own_e'(a)`` — for the moves e has expanded; moves a
     member has not discovered receive nothing (it cannot host a child row
     for them), which is the standard root-parallel partial-merge semantics.
+
+    On a forest sharded over the ensemble axis the partitioner keeps every
+    member's work on its own device: the sums over members are one
+    all-reduce of the per-move and root totals, (n_moves,) and a scalar
+    each for visits and wins, and the output keeps the input's sharding.
+    The forest is donated, so the untouched tree arrays are not copied.
     """
     dense_v, dense_w = jax.vmap(lambda t: root_move_stats(t, n_moves))(forest)
     own_v = dense_v - state.visits            # (E, M) each member's own work
@@ -444,13 +495,24 @@ def gscpm_search_batch(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
         raise ValueError(f"shard must be 'auto'|'off'|'require', "
                          f"got {shard!r}")
     n_moves = cfg.game_obj.n_actions  # the Game seam's move-id space
+    mesh = ensemble_mesh() if shard != "off" else None
+    if shard == "require" and mesh is None:
+        raise RuntimeError(
+            f"shard='require' needs two or more devices; JAX sees "
+            f"{len(jax.devices())} (README 'Scaling out')")
+    n_devices = 1 if mesh is None else mesh.size
+    Ep = E if mesh is None else ensemble_sharding(E, mesh)[1]
+    schedule = sched.make_schedule(
+        cfg.n_playouts, cfg.n_tasks, cfg.n_workers, cfg.scheduler)
+    merge_after = [merge_every > 0 and ((r + 1) % merge_every == 0
+                                        or r == len(schedule) - 1)
+                   for r in range(len(schedule))]
 
-    with span("gscpm_search"):
+    with span("gscpm_search", n_devices=n_devices, padded_members=Ep - E,
+              n_syncs=sum(merge_after)):
         with span("search_init"):
             reused_nodes = 0
-            if forest is None:
-                forest = init_forest(E, cfg.tree_cap, n_moves, to_move)
-            else:
+            if forest is not None:
                 if forest_size(forest) != E:
                     raise ValueError(
                         f"warm forest has {forest_size(forest)} members, "
@@ -459,28 +521,13 @@ def gscpm_search_batch(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
                 tm = int(np.asarray(to_move).reshape(-1)[0])
                 warm_tree_check(forest, tm, cfg)
                 reused_nodes = int(np.asarray(forest.n_nodes).sum()) - E
-            mesh = ensemble_mesh() if shard != "off" else None
-            if shard == "require" and mesh is None:
-                raise RuntimeError(
-                    f"shard='require' needs two or more devices; JAX sees "
-                    f"{len(jax.devices())} (README 'Scaling out')")
-            padded_members = 0
-            Ep = E
-            if mesh is not None:
-                sharding, Ep = ensemble_sharding(E, mesh)
-                padded_members = Ep - E
-                forest, boards = pad_forest_members(forest, boards, Ep, cfg,
-                                                    to_move)
-                member_keys = fold_task_keys(key,
-                                             jnp.arange(Ep, dtype=jnp.int32))
-                forest, boards, member_keys = jax.device_put(
-                    (forest, boards, member_keys), sharding)
-            else:
-                member_keys = fold_task_keys(key,
-                                             jnp.arange(E, dtype=jnp.int32))
-            schedule = sched.make_schedule(
-                cfg.n_playouts, cfg.n_tasks, cfg.n_workers, cfg.scheduler)
-            state = init_sync_state(Ep, n_moves) if merge_every > 0 else None
+            with span("forest_place"):
+                forest, boards, member_keys = place_forest(
+                    boards, to_move, cfg, key, mesh, forest)
+            state = None
+            if merge_every > 0:
+                state = jax.device_put(init_sync_state(Ep, n_moves),
+                                       forest.visits.sharding)
             metrics = None
             if cfg.metrics:
                 from repro.obsv.search_metrics import (
@@ -509,16 +556,16 @@ def gscpm_search_batch(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
                 if tracer:
                     jax.block_until_ready(forest.visits)
             playouts_per_tree += tasks * m
-            if merge_every > 0 and ((r + 1) % merge_every == 0
-                                    or r == len(schedule) - 1):
-                forest, state = sync_root_stats(forest, state, n_moves)
+            if merge_after[r]:
+                with span("forest_merge", tracer, round=r, sync=n_syncs):
+                    forest, state = sync_root_stats(forest, state, n_moves)
                 n_syncs += 1
         with span("search_wait"):
             jax.block_until_ready(forest.visits)
         dt = time.perf_counter() - t0
 
         with span("search_stats"):
-            if padded_members:
+            if Ep > E:
                 forest = jax.tree.map(lambda x: x[:E], forest)
                 if cfg.metrics:
                     metrics = jax.tree.map(lambda x: x[:E], metrics)
@@ -534,13 +581,12 @@ def gscpm_search_batch(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
                 "grain": cfg.grain,
                 "n_syncs": n_syncs,
                 "sharded": mesh is not None,
-                "n_devices": (1 if mesh is None
-                              else int(np.prod(mesh.devices.shape))),
+                "n_devices": n_devices,
                 "mesh_shape": (None if mesh is None
                                else dict(zip(mesh.axis_names,
                                              (int(d) for d in
                                               mesh.devices.shape)))),
-                "padded_members": padded_members,
+                "padded_members": Ep - E,
                 "tree_nodes": [int(n) for n in np.asarray(forest.n_nodes)],
                 "member_best_moves": summary["member_best_moves"].tolist(),
                 "member_root_values": summary["member_root_values"].tolist(),

@@ -49,14 +49,13 @@ import numpy as np
 
 from repro.core import game as game_mod
 from repro.core import scheduler as sched
-from repro.core.gscpm import (GSCPMConfig, fold_task_keys,
-                              run_schedule_round, warm_tree_check)
-from repro.core.root_parallel import (ensemble_mesh, ensemble_sharding,
-                                      forest_retire_summary,
+from repro.core.gscpm import (GSCPMConfig, run_schedule_round,
+                              warm_tree_check)
+from repro.core.root_parallel import (ensemble_mesh, forest_retire_summary,
                                       materialize_forest_summary,
-                                      pad_forest_members,
+                                      place_forest,
                                       run_schedule_round_forest)
-from repro.core.tree import (Tree, init_forest, init_tree,
+from repro.core.tree import (Tree, forest_size, init_tree,
                              materialize_root_summary, reroot_tree,
                              root_summary_device)
 from repro.serve import resilience
@@ -462,17 +461,10 @@ class TPFIFOGameEngine(TPFIFODriver):
         req = t.req
         E = cfg.n_trees
         mesh = ensemble_mesh()
-        sharding, Ep = ensemble_sharding(E, mesh)
-        forest = init_forest(E, cfg.tree_cap, cfg.game_obj.n_actions,
-                             req.to_move)
-        boards = jnp.tile(board[None, :], (E, 1))
-        forest, boards = pad_forest_members(forest, boards, Ep, cfg,
-                                            req.to_move)
-        member_keys = fold_task_keys(jax.random.key(req.seed),
-                                     jnp.arange(Ep, dtype=jnp.int32))
-        if sharding is not None:
-            forest, boards, member_keys = jax.device_put(
-                (forest, boards, member_keys), sharding)
+        forest, boards, member_keys = place_forest(
+            jnp.tile(board[None, :], (E, 1)), req.to_move, cfg,
+            jax.random.key(req.seed), mesh)
+        Ep = forest_size(forest)
         metrics = None
         if cfg.metrics:
             from repro.obsv.search_metrics import init_search_metrics_forest

@@ -114,3 +114,34 @@ def test_fails_without_the_repository(tmp_path):
     proc = _run_alone(alone / "chip_smoke.py", tmp_path)
     assert proc.returncode != 0
     assert '"ok"' not in proc.stdout
+
+
+FOREST_REHEARSAL = r"""
+import importlib.util, sys
+spec = importlib.util.spec_from_file_location("chip_smoke", sys.argv[1])
+smoke = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(smoke)
+from repro.core.gscpm import GSCPMConfig
+cfg = GSCPMConfig(board_size=5, n_playouts=64, n_tasks=8, n_workers=4,
+                  tree_cap=320)
+smoke.forest_phase(cfg, seed=0, n_playouts=32, n_tasks=8, tree_cap=320)
+"""
+
+
+def test_forest_phase_on_four_virtual_devices():
+    """``--four-chips``' phase at tiny widths on four virtual CPU devices
+    (a child process: the device count is fixed when JAX starts): the
+    forest, merged after each of its 2 rounds, is the same in every tree
+    array sharded and on one device."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in (str(ROOT / "src"),
+                               os.environ.get("PYTHONPATH")) if p),
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
+                          " --xla_force_host_platform_device_count=4").strip())
+    proc = subprocess.run(
+        [sys.executable, "-c", FOREST_REHEARSAL, str(ROOT / "chip_smoke.py")],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "2 merges, on 4 device(s)" in proc.stdout
+    assert "sharded vs one chip: bit-identical" in proc.stdout

@@ -554,9 +554,9 @@ def test_phase_scopes_in_compiled_programs(program):
                    for p in paths)
 
 
-def _profile(tmp_path, fn):
+def _profile(tmp_path, fn, names=SEARCH_SPANS):
     """Run ``fn`` under the JAX profiler; return its host-plane events
-    named by the search spans as (start_ns, name, stats dict)."""
+    named in ``names`` as (start_ns, name, stats dict)."""
     jax.profiler.start_trace(str(tmp_path))
     try:
         out = fn()
@@ -568,7 +568,7 @@ def _profile(tmp_path, fn):
         (e.start_ns, e.name, dict(e.stats))
         for plane in pd.planes if plane.name.startswith("/host:")
         for line in plane.lines for e in line.events
-        if e.name in SEARCH_SPANS)
+        if e.name in names)
     return out, events
 
 
@@ -599,6 +599,31 @@ def test_profiler_trace_holds_the_search_spans(tmp_path, driver):
     # the round spans lie inside the search span, after its init
     start = {n: s for s, n, _ in events}
     assert start["gscpm_search"] <= start["search_init"] < min(
+        s for s, n, _ in events if n == "gscpm_round")
+
+
+def test_profiler_trace_holds_the_forest_spans(tmp_path):
+    """The forest's own spans: the placement once, one ``forest_merge`` per
+    merge with its round and index, and the search span's placement and
+    merge counts."""
+    cfg = cfg_for("hex", n_playouts=48, n_tasks=12)
+    board = cfg.game_obj.init_board()
+    run = lambda: gscpm_search_batch(  # noqa: E731
+        board, 1, cfg, jax.random.key(3), n_trees=2, merge_every=2,
+        shard="off")
+    run()                                  # compile outside the trace
+    (_, st), events = _profile(tmp_path, run, names=(
+        "gscpm_search", "forest_place", "forest_merge", "gscpm_round"))
+    names = [n for _, n, _ in events]
+    assert names.count("forest_place") == 1
+    merges = [a for _, n, a in events if n == "forest_merge"]
+    # 3 rounds, merged after round 1 and, as always, after the last
+    assert [(a["round"], a["sync"]) for a in merges] == [(1, 0), (2, 1)]
+    (search,) = [a for _, n, a in events if n == "gscpm_search"]
+    assert (search["n_devices"], search["padded_members"],
+            search["n_syncs"]) == (1, 0, st["n_syncs"]) == (1, 0, 2)
+    start = {n: s for s, n, _ in events}
+    assert start["gscpm_search"] <= start["forest_place"] < min(
         s for s, n, _ in events if n == "gscpm_round")
 
 

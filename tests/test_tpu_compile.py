@@ -4,8 +4,9 @@ On a TPU, ``kernels.ops`` routes every search through two compiled Pallas
 kernels: ``uct_select`` (one (W, C) tile per descent level) and
 ``hex_winner`` (every Hex playout). Interpret mode cannot see what Mosaic
 refuses (unsupported shape casts, tiling, VMEM), so these tests compile
-both kernels, and the whole paper-width search program, for a ``v5e:2x2``
-topology that is described here and not attached. Nothing runs.
+both kernels, the whole paper-width search program, and the root-parallel
+forest's programs over the four chips, for a ``v5e:2x2`` topology that is
+described here and not attached. Nothing runs.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -16,35 +17,41 @@ written for a chip that is not attached cannot be read back here.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels import hex_winner as hw
 from repro.kernels import uct_select as us
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -82,6 +89,8 @@ def test_uct_select_compiles_under_forest_vmap(one_chip):
 # the Pallas call as the compiled program names it: the device op's name,
 # which the benchmark's roofline and phase readers look for, comes from the
 # jitted function ``hex_winner`` around it
+COLLECTIVE = re.compile(r"all-reduce|all-gather|all-to-all|collective-permute|"
+                        r"reduce-scatter")
 HEX_WINNER_CALL = re.compile(
     r'^\s*%hex_winner(\.\d+)? = .* custom-call\(.*'
     r'custom_call_target="tpu_custom_call"', re.M)
@@ -124,3 +133,64 @@ def test_paper_search_program_compiles_for_tpu(one_chip, monkeypatch):
     # the donated tree is updated in place; scratch stays far below HBM
     assert mem.alias_size_in_bytes >= 0.99 * mem.argument_size_in_bytes
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_forest_programs_compile_for_four_chips(topo, monkeypatch):
+    """``hex11-forest8`` over the described 2x2 host (8 trees at PAPER's
+    widths, tree cap 2^17, 2 per chip): the placement builds each chip's
+    own members, the round runs both kernels with no collective and
+    updates the donated forest in place, and the merge exchanges one
+    all-reduce of the root totals, (121,) and scalars, in place too."""
+    from repro.configs.hex_paper import PAPER
+    from repro.core import root_parallel as rp
+    from repro.core.gscpm import fold_task_keys
+    from repro.core.tree import init_forest
+    from repro.launch.mesh import make_ensemble_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(PAPER, n_playouts=124_928, n_tasks=488,
+                              tree_cap=1 << 17)
+    E, W, M = 8, cfg.n_workers, cfg.game_obj.n_actions
+    mesh = make_ensemble_mesh(list(topo.devices))
+    ens = NamedSharding(mesh, rp.ensemble_spec(mesh))
+    rep = NamedSharding(mesh, PartitionSpec())
+    place = lambda x: _spec(x.shape, x.dtype, ens)  # noqa: E731
+    forest = jax.tree.map(place, jax.eval_shape(
+        lambda: init_forest(E, cfg.tree_cap, M, 1)))
+    member_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(forest)) // E
+
+    init = rp._init_forest_placed.lower(
+        _spec((E,), jnp.int32, rep), n_trees=E, cap=cfg.tree_cap,
+        max_children=M, sharding=ens).compile()
+    assert init.memory_analysis().output_size_in_bytes < 3 * member_bytes
+    assert not COLLECTIVE.search(init.as_text())
+
+    keys = place(jax.eval_shape(lambda: rp.fold_member_task_keys(
+        fold_task_keys(jax.random.key(0), jnp.arange(E)), jnp.arange(W))))
+    chunk = rp._sharded_chunk.lower(
+        forest, _spec((E, cfg.game_obj.n_cells), jnp.int8, ens), keys,
+        _spec((E, W), jnp.bool_, ens), _spec((), jnp.int32, rep),
+        _spec((), jnp.float32, rep), cfg=cfg, mesh=mesh).compile()
+    text = chunk.as_text()
+    assert len(HEX_WINNER_CALL.findall(text)) == 1
+    assert text.count("tpu_custom_call") >= 2
+    assert not COLLECTIVE.search(text)
+    mem = chunk.memory_analysis()
+    assert mem.alias_size_in_bytes >= 0.99 * 2 * member_bytes
+    assert mem.temp_size_in_bytes < 1 << 30
+
+    state = jax.tree.map(place, jax.eval_shape(
+        lambda: rp.init_sync_state(E, M)))
+    merge = rp.sync_root_stats.lower(forest, state, M).compile()
+    text = merge.as_text()
+    reduces = re.findall(r"= \(?([^=]*?)\)? all-reduce(?:-start)?\(", text)
+    assert len(reduces) == 1, reduces
+    assert set(re.findall(r"[a-z0-9]+\[([0-9,]*)\]", reduces[0])) <= {
+        "", str(M)}, reduces
+    assert not re.search(r"all-gather|all-to-all|collective-permute|"
+                         r"reduce-scatter", text)
+    assert merge.memory_analysis().alias_size_in_bytes >= 0.99 * 2 * \
+        member_bytes
+    for s in jax.tree.leaves(merge.output_shardings):
+        assert s.is_equivalent_to(ens, 1)
