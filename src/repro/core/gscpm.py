@@ -190,17 +190,26 @@ def advance_paths(paths: jnp.ndarray, depths: jnp.ndarray, child: jnp.ndarray,
 
 
 def select_batch(tree: Tree, root_board: jnp.ndarray, game, cp,
-                 noise_keys: jax.Array, noise_scale: float):
+                 noise_keys: jax.Array, noise_scale: float, *,
+                 read_vloss: bool = True):
     """Level-synchronous batched descent: all W lanes in lockstep.
 
-    Each level gathers the lanes' child stats into one (W, C) tile
-    (``tree.child_stat_tile``) and picks all W children with a single
-    ``kernels.ops.uct_select`` call — the Pallas VPU kernel on TPU, the jnp
-    reference elsewhere (DESIGN.md §11). Lanes that reached a
-    not-fully-expanded or terminal node (or the depth cap) are masked out of
-    the tile and held in place. Bit-identical to ``jax.vmap(select_one)``
-    under the same RNG schedule (the per-lane oracle; pinned in
-    tests/test_batched_descent.py).
+    A lane steps from its node while the node is fully expanded, not
+    terminal and above the depth cap (``go``). Each level scores all W
+    lanes' children with a single ``kernels.ops.uct_select`` call — the
+    Pallas VPU kernel on TPU, the jnp reference elsewhere (DESIGN.md §11) —
+    and lanes that do not step are masked out of the tile and held in place.
+    Every lane starts at the root, so level 0 reads the root's child stats
+    once, as one (1, C) row broadcast to the W lanes. Below it, each trip of
+    the level loop gathers the lanes' (W, C) tile (``tree.child_stat_tile``)
+    and ``any(go)`` is the loop's own condition: a trip runs only where some
+    lane steps, so the level at which every lane stops gathers no tile (and
+    a root that is not fully expanded costs its row and no tile).
+    ``read_vloss=False`` (a tree whose virtual loss is zero) reads no
+    virtual loss at all.
+
+    Bit-identical to ``jax.vmap(select_one)`` under the same RNG schedule
+    (the per-lane oracle; pinned in tests/test_batched_descent.py).
 
     Returns (paths, depths, leaves, boards, n_empty), each batched over W.
     """
@@ -209,40 +218,49 @@ def select_batch(tree: Tree, root_board: jnp.ndarray, game, cp,
     C = tree.max_children
     W = noise_keys.shape[0]
 
-    nodes0 = jnp.zeros((W,), jnp.int32)
-    boards0 = jnp.tile(root_board[None, :], (W, 1))
-    depths0 = jnp.zeros((W,), jnp.int32)
-    paths0 = jnp.full((W, max_depth), cap, dtype=jnp.int32).at[:, 0].set(0)
-    n_empty0 = jnp.broadcast_to(
-        (root_board == EMPTY).sum().astype(jnp.int32), (W,))
-    done0 = jnp.zeros((W,), bool)
+    def go_at(nodes, depths, n_empty):
+        fully = (tree.n_children[nodes] == n_empty) & (n_empty != 0)
+        return fully & (depths < max_depth - 2)
 
-    def cond(st):
-        return ~st[-1].all()
-
-    def body(st):
-        nodes, boards, depths, paths, n_empty, done = st
-        n_kids = tree.n_children[nodes]
-        terminal = n_empty == 0
-        fully = (n_kids == n_empty) & ~terminal
-        safe, valid, wins, visits, vloss, ptot = child_stat_tile(tree, nodes)
+    def pick(tile, depths, go):
+        safe, valid, wins, visits, vloss, ptot = tile
         noise = (level_noise(noise_keys, depths, C, noise_scale)
                  if noise_scale > 0.0 else None)
         picks = ops.uct_select(wins, visits, vloss, ptot, valid, cp,
-                               noise=noise, lane_mask=~done)
-        child = safe[jnp.arange(W), picks]
+                               noise=noise, lane_mask=go)
+        return safe[jnp.arange(W), picks]
+
+    def step(st, child):
+        nodes, boards, depths, paths, n_empty, go = st
         mv = tree.move[child]
         new_boards = jax.vmap(game.place)(boards, mv, tree.to_move[nodes])
-        step = fully & (depths < max_depth - 2) & ~done
-        nodes = jnp.where(step, child, nodes)
-        boards = jnp.where(step[:, None], new_boards, boards)
-        paths = advance_paths(paths, depths, child, step)
-        depths = jnp.where(step, depths + 1, depths)
-        n_empty = jnp.where(step, n_empty - 1, n_empty)
-        return nodes, boards, depths, paths, n_empty, done | ~step
+        nodes = jnp.where(go, child, nodes)
+        boards = jnp.where(go[:, None], new_boards, boards)
+        paths = advance_paths(paths, depths, child, go)
+        depths = jnp.where(go, depths + 1, depths)
+        n_empty = jnp.where(go, n_empty - 1, n_empty)
+        return nodes, boards, depths, paths, n_empty, go_at(nodes, depths,
+                                                             n_empty)
+
+    n_empty_root = (root_board == EMPTY).sum().astype(jnp.int32)
+    zeros = jnp.zeros((W,), jnp.int32)
+    st = (zeros, jnp.tile(root_board[None, :], (W, 1)), zeros,
+          jnp.full((W, max_depth), cap, dtype=jnp.int32).at[:, 0].set(0),
+          jnp.broadcast_to(n_empty_root, (W,)),
+          jnp.broadcast_to(go_at(jnp.int32(0), jnp.int32(0), n_empty_root),
+                           (W,)))
+    root_row = child_stat_tile(tree, zeros[:1], read_vloss=read_vloss)
+    st = step(st, pick(
+        [jnp.broadcast_to(x, (W,) + x.shape[1:]) for x in root_row],
+        zeros, st[-1]))
+
+    def body(st):
+        nodes, _, depths, _, _, go = st
+        tile = child_stat_tile(tree, nodes, read_vloss=read_vloss)
+        return step(st, pick(tile, depths, go))
 
     nodes, boards, depths, paths, n_empty, _ = jax.lax.while_loop(
-        cond, body, (nodes0, boards0, depths0, paths0, n_empty0, done0))
+        lambda st: st[-1].any(), body, st)
     return paths, depths, nodes, boards, n_empty
 
 
@@ -376,8 +394,10 @@ def sync_iteration(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
                 return path, depth, leaf, board, mv
             out = jax.vmap(one)(k_noise, k_move)
         else:
+            # one selection round writes no virtual loss: it reads none
             paths, depths, leaves, boards, _ = select_batch(
-                tree_r, root_board, game, cp, k_noise, cfg.select_noise)
+                tree_r, root_board, game, cp, k_noise, cfg.select_noise,
+                read_vloss=R > 1)
             mvs = jax.vmap(
                 lambda l, b, k: propose_move(tree_r, l, b, game, k)
             )(leaves, boards, k_move)

@@ -113,7 +113,8 @@ def add_vloss(tree: Tree, paths: jnp.ndarray, weights: jnp.ndarray,
     return tree._replace(vloss=vloss)
 
 
-def child_stat_tile(tree: Tree, nodes: jnp.ndarray):
+def child_stat_tile(tree: Tree, nodes: jnp.ndarray, *,
+                    read_vloss: bool = True):
     """Gather the child statistics of a (W,) node batch as (W, C) tiles.
 
     Returns ``(safe, valid, wins, visits, vloss, parent_total)``: ``safe``
@@ -122,15 +123,23 @@ def child_stat_tile(tree: Tree, nodes: jnp.ndarray):
     each node's visits + virtual loss. This is the gather feeding one
     level-synchronous ``kernels.ops.uct_select`` call — all W lanes of a
     descent score one tree level in a single (W, C) tile (DESIGN.md §11).
+
+    ``read_vloss=False`` is for a tree whose virtual loss is zero (a search
+    with one selection round per iteration never writes it): ``vloss`` is
+    not read at all, its tile is zeros made without a gather and the parent
+    total is the node's visits — the same values, bit for bit.
     """
     C = tree.max_children
     cap = tree.cap
     slots = tree.children[nodes]                                   # (W, C)
     valid = jnp.arange(C, dtype=jnp.int32)[None, :] < tree.n_children[nodes][:, None]
     safe = jnp.where(valid, slots, cap)
+    wins, visits = tree.wins[safe], tree.visits[safe]
+    if not read_vloss:
+        return (safe, valid, wins, visits, jnp.zeros_like(visits),
+                tree.visits[nodes])
     parent_total = tree.visits[nodes] + tree.vloss[nodes]          # (W,)
-    return (safe, valid, tree.wins[safe], tree.visits[safe],
-            tree.vloss[safe], parent_total)
+    return safe, valid, wins, visits, tree.vloss[safe], parent_total
 
 
 def best_child(tree: Tree) -> jnp.ndarray:

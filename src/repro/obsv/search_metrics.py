@@ -46,6 +46,9 @@ class SearchMetrics(NamedTuple):
     depth_sum: jnp.ndarray              # Σ descent depth over active lanes
     depth_max: jnp.ndarray              # deepest descent seen
     held_levels: jnp.ndarray            # lane-levels idled while peers descended
+    # (W, C) child-statistics tiles the level loop gathered: one per level
+    # below the root from which some lane stepped (gscpm.select_batch)
+    tile_gathers: jnp.ndarray
     expand_proposals: jnp.ndarray       # (leaf, move) expansion proposals
     expansions: jnp.ndarray             # nodes actually allocated
     expand_collisions: jnp.ndarray      # duplicate proposals collapsed
@@ -122,8 +125,9 @@ def accumulate_iteration(m: SearchMetrics, *, depths_grouped: jnp.ndarray,
     Every input is a value the iteration computed anyway:
 
     - ``depths_grouped``: (R, Wr) descent depths, grouped by virtual-loss
-      round — held levels are counted against each group's own deepest
-      lane, because that is the lockstep descent the group actually ran;
+      round — held levels and level-loop trips are counted against each
+      group's own deepest lane, because that is the lockstep descent the
+      group actually ran;
     - ``leaves``/``moves``: (W,) selected leaves and proposed expansion
       moves (−1 = no proposal);
     - ``eval_boards``: (W, n_cells) positions handed to the playout stage
@@ -139,6 +143,9 @@ def accumulate_iteration(m: SearchMetrics, *, depths_grouped: jnp.ndarray,
 
     group_max = depths_grouped.max(axis=1, keepdims=True)
     held = (group_max - depths_grouped).sum().astype(jnp.int32)
+    # a group's level loop steps all its moving lanes one level per trip,
+    # from depth 1 down, so its trips are its deepest lane's depth - 1
+    tiles = jnp.maximum(group_max - 1, 0).sum().astype(jnp.int32)
 
     proposals = ((moves >= 0) & active).astype(jnp.int32).sum()
     playout_len = (eval_boards == EMPTY).sum(axis=1).astype(jnp.int32)
@@ -151,6 +158,7 @@ def accumulate_iteration(m: SearchMetrics, *, depths_grouped: jnp.ndarray,
         depth_sum=m.depth_sum + (depths * act_i).sum(),
         depth_max=jnp.maximum(m.depth_max, (depths * act_i).max()),
         held_levels=m.held_levels + held,
+        tile_gathers=m.tile_gathers + tiles,
         expand_proposals=m.expand_proposals + proposals,
         expansions=m.expansions + (n_nodes_after - n_nodes_before),
         expand_collisions=m.expand_collisions
@@ -192,6 +200,8 @@ def summarize_metrics(m: SearchMetrics) -> dict:
                                       jax.device_get(tuple(m)))}
     playouts = max(1, host["lane_playouts"])
     host["depth_mean"] = host["depth_sum"] / playouts
+    host["tile_gathers_per_iter"] = (
+        host["tile_gathers"] / max(1, host["sync_iterations"]))
     host["playout_len_mean"] = host["playout_moves"] / playouts
     host["expand_collision_rate"] = (
         host["expand_collisions"] / max(1, host["expand_proposals"]))

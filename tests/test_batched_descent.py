@@ -21,7 +21,7 @@ from repro.core import hex as hx
 from repro.core.gscpm import (GSCPMConfig, expand_batch, gscpm_search,
                               run_chunk, select_batch, select_one)
 from repro.core.root_parallel import gscpm_search_batch
-from repro.core.tree import child_stat_tile, init_tree
+from repro.core.tree import best_child, child_stat_tile, init_tree, reroot_tree
 from repro.kernels import ops
 from repro.kernels import uct_select as _us
 from repro.serve.mcts_decode import (MCTSDecodeConfig, backup_values,
@@ -38,11 +38,45 @@ def built_tree(size: int, key, n_playouts: int = 192):
 
 
 # ------------------------------------------------------- descent oracle ----
-@pytest.mark.parametrize("size", [5, 7])
-@pytest.mark.parametrize("W", [1, 4, 16])
-@pytest.mark.parametrize("noise_scale", [0.0, 1e-3])
-def test_select_batch_matches_vmapped_select_one(size, W, noise_scale):
-    tree, board, spec = built_tree(size, jax.random.PRNGKey(size))
+def open_root_tree(size: int, key):
+    """A tree whose root is not fully expanded: no lane steps from it."""
+    tree, board, game = built_tree(size, key, n_playouts=8)
+    assert int(tree.n_children[0]) < size * size
+    return tree, board, game
+
+
+def terminal_gomoku_root(size: int, key):
+    """A Gomoku tree rooted at a won position: no legal move, no child."""
+    cfg = GSCPMConfig(game="gomoku", board_size=size, n_playouts=32,
+                      n_tasks=4, n_workers=4, tree_cap=256)
+    game = cfg.game_obj
+    board = game.init_board().at[jnp.arange(5) * size].set(1)   # a column
+    tree, _ = gscpm_search(board, 2, cfg, key)
+    assert int(tree.n_children[0]) == 0 and float(tree.visits[0]) > 0
+    return tree, board, game
+
+
+def rerooted_tree(size: int, key):
+    """A warm tree: a searched tree re-rooted at its most visited move."""
+    tree, board, game = built_tree(size, key, n_playouts=512)
+    mv = int(best_child(tree))
+    warm = reroot_tree(tree, mv)
+    assert int(warm.n_nodes) > 1 and int(warm.n_children[0]) > 0
+    return warm, game.place(board, jnp.int32(mv), jnp.int8(1)), game
+
+
+TREES = {"built": built_tree, "root_open": open_root_tree,
+         "gomoku_terminal": terminal_gomoku_root, "reroot_warm": rerooted_tree}
+DESCENT_CASES = [
+    pytest.param(noise_scale, W, size, "built", id=f"{noise_scale}-{W}-{size}")
+    for noise_scale in (0.0, 1e-3) for W in (1, 4, 16) for size in (5, 7)
+] + [pytest.param(1e-3, 16, 5, kind, id=f"0.001-16-5-{kind}")
+     for kind in ("root_open", "gomoku_terminal", "reroot_warm")]
+
+
+@pytest.mark.parametrize("noise_scale,W,size,kind", DESCENT_CASES)
+def test_select_batch_matches_vmapped_select_one(size, W, noise_scale, kind):
+    tree, board, spec = TREES[kind](size, jax.random.PRNGKey(size))
     keys = jax.random.split(jax.random.PRNGKey(100 + W), W)
     cp = jnp.float32(1.0)
     want = jax.vmap(
@@ -52,6 +86,48 @@ def test_select_batch_matches_vmapped_select_one(size, W, noise_scale):
                           want, got):
         np.testing.assert_array_equal(np.asarray(w), np.asarray(g),
                                       err_msg=f"{name} diverged")
+    if kind in ("root_open", "gomoku_terminal"):
+        assert not np.asarray(got[1]).any()        # every lane at the root
+
+
+def test_select_batch_reads_no_vloss_when_told():
+    """With ``read_vloss=False`` a NaN virtual loss changes no path: it is
+    never read. Read, the same NaN does change them."""
+    tree, board, spec = built_tree(5, jax.random.PRNGKey(4))
+    nan = tree._replace(vloss=jnp.full_like(tree.vloss, jnp.nan))
+    keys = jax.random.split(jax.random.PRNGKey(9), 16)
+    cp = jnp.float32(1.0)
+    want = select_batch(tree, board, spec, cp, keys, 1e-3)
+    got = select_batch(nan, board, spec, cp, keys, 1e-3, read_vloss=False)
+    for name, w, g in zip(("path", "depth", "leaf", "board", "n_empty"),
+                          want, got):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g),
+                                      err_msg=f"{name} diverged")
+    read = select_batch(nan, board, spec, cp, keys, 1e-3)
+    assert not np.array_equal(np.asarray(read[0]), np.asarray(want[0]))
+
+
+def test_run_chunk_one_round_reads_no_vloss():
+    """At ``vl_rounds == 1`` the compiled round never reads ``vloss``: a
+    tree holding NaN there grows exactly as one holding zeros."""
+    board = hx.empty_board(hx.HexSpec(5))
+    cfg = GSCPMConfig(board_size=5, n_workers=8, tree_cap=1024)
+    key = jax.random.PRNGKey(21)
+    task_keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(
+        jnp.arange(8, dtype=jnp.int32))
+    active = jnp.ones((8,), bool)
+    grown = []
+    for fill in (0.0, jnp.nan):
+        tree = init_tree(cfg.tree_cap, 25, 1)
+        tree = tree._replace(vloss=jnp.full_like(tree.vloss, fill))
+        grown.append(run_chunk(tree, board, cfg, task_keys, active,
+                               jnp.int32(24), jnp.float32(1.0)))
+    zero, nan = grown
+    assert int(zero.n_nodes) > 100
+    for f in ("parent", "move", "to_move", "children", "n_children",
+              "visits", "wins", "n_nodes"):
+        np.testing.assert_array_equal(np.asarray(getattr(zero, f)),
+                                      np.asarray(getattr(nan, f)), err_msg=f)
 
 
 @pytest.mark.parametrize("vl_rounds", [1, 3])

@@ -179,12 +179,63 @@ def test_forest_summary_merges_members():
 
 def test_merge_metrics_sum_vs_max_fields():
     a = init_search_metrics()._replace(
-        expansions=jnp.int32(3), tree_nodes_peak=jnp.int32(10))
+        expansions=jnp.int32(3), tree_nodes_peak=jnp.int32(10),
+        tile_gathers=jnp.int32(5), sync_iterations=jnp.int32(2))
     b = init_search_metrics()._replace(
-        expansions=jnp.int32(4), tree_nodes_peak=jnp.int32(8))
+        expansions=jnp.int32(4), tree_nodes_peak=jnp.int32(8),
+        tile_gathers=jnp.int32(2), sync_iterations=jnp.int32(2))
     c = merge_metrics(a, b)
     assert int(c.expansions) == 7
     assert int(c.tree_nodes_peak) == 10
+    assert int(c.tile_gathers) == 7                 # summed counter
+    assert summarize_metrics(c)["tile_gathers_per_iter"] == 7 / 4
+
+
+@pytest.mark.parametrize("vl_rounds", [1, 2])
+def test_tile_gathers_counts_levels_stepped_from(monkeypatch, vl_rounds):
+    """``tile_gathers`` is the (W, C) tiles the descent's level loop really
+    gathered (counted here at each gather the compiled loop runs): one per
+    level below the root from which some lane stepped — none at the root,
+    whose stats are one shared row, and none at the level where every lane
+    stops."""
+    from repro.core import gscpm
+    from repro.core.gscpm import select_one
+
+    gathered = []
+    real = gscpm.child_stat_tile
+
+    def counting(tree, nodes, **kw):
+        if nodes.shape[0] > 1:                      # a tile, not the root row
+            jax.debug.callback(lambda n: gathered.append(1), nodes[0])
+        return real(tree, nodes, **kw)
+
+    monkeypatch.setattr(gscpm, "child_stat_tile", counting)
+    W = 8
+    cfg = cfg_for("hex", metrics=True, n_workers=W, vl_rounds=vl_rounds,
+                  board_size=3, tree_cap=2048)
+    game = cfg.game_obj
+    board = game.init_board()
+    one = jax.jit(lambda t, k, mx: gscpm.sync_iteration(
+        t, board, cfg, jnp.float32(1.0), k, jnp.ones((W,), bool), mx))
+    # the oracle: each lane's own scalar descent, on the same noise keys
+    depths_of = jax.jit(lambda t, keys: jax.vmap(lambda k: select_one(
+        t, board, game, jnp.float32(1.0), jax.random.split(k, 3)[0],
+        cfg.select_noise)[1])(keys))
+    tree = init_tree(cfg.tree_cap, game.n_actions, 1)
+    mx = init_search_metrics()
+    stepped = []
+    for i in range(100):
+        keys = jax.random.split(jax.random.key(i), W)
+        if vl_rounds == 1:
+            stepped.append(max(int(depths_of(tree, keys).max()) - 1, 0))
+        tree, mx = one(tree, keys, mx)
+    jax.effects_barrier()
+    m = summarize_metrics(mx)
+    assert m["depth_max"] >= 3                      # the loop did run
+    assert m["tile_gathers"] == len(gathered) > 0
+    if vl_rounds == 1:
+        assert stepped[0] == 0                      # root not yet expanded
+        assert m["tile_gathers"] == sum(stepped)
 
 
 # ------------------------------------------------------------------ tracer ----

@@ -127,8 +127,14 @@ def test_paper_search_program_compiles_for_tpu(one_chip, monkeypatch):
         _spec((cfg.n_workers,), jnp.bool_, one_chip),
         _spec((), jnp.int32, one_chip),
         _spec((), jnp.float32, one_chip)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 2
-    assert len(HEX_WINNER_CALL.findall(compiled.as_text())) == 1
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert len(HEX_WINNER_CALL.findall(text)) == 1
+    # one level's (W, C) child statistics: wins and visits, and no virtual
+    # loss, which one selection round per iteration never writes
+    tiles = re.findall(rf"= f32\[{cfg.n_workers * game.n_actions}\]\S* "
+                       r"fusion\(.*descent/.*gather", text)
+    assert len(tiles) == 2, tiles
     mem = compiled.memory_analysis()
     # the donated tree is updated in place; scratch stays far below HBM
     assert mem.alias_size_in_bytes >= 0.99 * mem.argument_size_in_bytes
