@@ -54,7 +54,6 @@ from repro.core.tree import (
     best_child,
     child_stat_tile,
     init_tree,
-    reset_vloss,
     root_value,
 )
 from repro.kernels import ops
@@ -89,8 +88,6 @@ class GSCPMConfig:
     tree_cap: int = 1 << 15
     # fifo | rebalance | one_per_core | sequential
     scheduler: str = dataclasses.field(default="fifo", compare=False)
-    descent: str = "batched"        # batched (level-synchronous) | scalar (oracle)
-    playout: str = "batched"        # batched (fused (W, cells)) | scalar (oracle)
     # device-plane observability (DESIGN.md §15): thread a SearchMetrics
     # accumulator through the compiled chunks. HASHED static flag: each
     # game class compiles exactly two programs (metrics on / off), and the
@@ -115,8 +112,11 @@ class GSCPMConfig:
 
 # ------------------------------------------------------------- selection ----
 def select_one(tree: Tree, root_board: jnp.ndarray, game, cp: float,
-               noise_key: jax.Array, noise_scale: float):
+               noise_key: jax.Array, noise_scale: float, vloss=None):
     """Descend from the root to a not-fully-expanded (or terminal) node.
+
+    The per-lane reference of ``select_batch``: tests compare the two, and
+    the search runs only the batched one.
 
     Returns (path, depth, leaf, board_at_leaf, n_empty_at_leaf). ``path`` is
     (max_depth,) int32 padded with the tree's PAD row index. A node counts
@@ -124,10 +124,13 @@ def select_one(tree: Tree, root_board: jnp.ndarray, game, cp: float,
     that end mid-board (e.g. a Gomoku five) never get there — their
     terminal nodes keep zero children because ``game.legal_mask`` is empty,
     so the descent stops at them without a per-level terminal test.
+    ``vloss`` is the iteration's virtual-loss array, or None for none.
     """
     max_depth = game.max_moves + 1
     cap = tree.cap
     C = tree.max_children
+    if vloss is None:
+        vloss = jnp.zeros_like(tree.visits)
 
     path0 = jnp.full((max_depth,), cap, dtype=jnp.int32).at[0].set(0)
     n_empty0 = (root_board == EMPTY).sum().astype(jnp.int32)
@@ -146,8 +149,8 @@ def select_one(tree: Tree, root_board: jnp.ndarray, game, cp: float,
         valid = jnp.arange(C, dtype=jnp.int32) < n_kids
         safe = jnp.where(valid, slots, cap)
         scores = uct_mod.uct_scores(
-            tree.wins[safe], tree.visits[safe], tree.vloss[safe],
-            tree.visits[node] + tree.vloss[node], cp, valid)
+            tree.wins[safe], tree.visits[safe], vloss[safe],
+            tree.visits[node] + vloss[node], cp, valid)
         noise = None
         if noise_scale > 0.0:
             noise = noise_scale * jax.random.uniform(
@@ -190,8 +193,7 @@ def advance_paths(paths: jnp.ndarray, depths: jnp.ndarray, child: jnp.ndarray,
 
 
 def select_batch(tree: Tree, root_board: jnp.ndarray, game, cp,
-                 noise_keys: jax.Array, noise_scale: float, *,
-                 read_vloss: bool = True):
+                 noise_keys: jax.Array, noise_scale: float, vloss=None):
     """Level-synchronous batched descent: all W lanes in lockstep.
 
     A lane steps from its node while the node is fully expanded, not
@@ -205,8 +207,9 @@ def select_batch(tree: Tree, root_board: jnp.ndarray, game, cp,
     and ``any(go)`` is the loop's own condition: a trip runs only where some
     lane steps, so the level at which every lane stops gathers no tile (and
     a root that is not fully expanded costs its row and no tile).
-    ``read_vloss=False`` (a tree whose virtual loss is zero) reads no
-    virtual loss at all.
+    ``vloss`` is the iteration's virtual-loss array (``sync_iteration``
+    with more than one selection round), or None: then no virtual loss is
+    read at all.
 
     Bit-identical to ``jax.vmap(select_one)`` under the same RNG schedule
     (the per-lane oracle; pinned in tests/test_batched_descent.py).
@@ -223,10 +226,10 @@ def select_batch(tree: Tree, root_board: jnp.ndarray, game, cp,
         return fully & (depths < max_depth - 2)
 
     def pick(tile, depths, go):
-        safe, valid, wins, visits, vloss, ptot = tile
+        safe, valid, wins, visits, vl, ptot = tile
         noise = (level_noise(noise_keys, depths, C, noise_scale)
                  if noise_scale > 0.0 else None)
-        picks = ops.uct_select(wins, visits, vloss, ptot, valid, cp,
+        picks = ops.uct_select(wins, visits, vl, ptot, valid, cp,
                                noise=noise, lane_mask=go)
         return safe[jnp.arange(W), picks]
 
@@ -249,14 +252,14 @@ def select_batch(tree: Tree, root_board: jnp.ndarray, game, cp,
           jnp.broadcast_to(n_empty_root, (W,)),
           jnp.broadcast_to(go_at(jnp.int32(0), jnp.int32(0), n_empty_root),
                            (W,)))
-    root_row = child_stat_tile(tree, zeros[:1], read_vloss=read_vloss)
+    root_row = child_stat_tile(tree, zeros[:1], vloss=vloss)
     st = step(st, pick(
         [jnp.broadcast_to(x, (W,) + x.shape[1:]) for x in root_row],
         zeros, st[-1]))
 
     def body(st):
         nodes, _, depths, _, _, go = st
-        tile = child_stat_tile(tree, nodes, read_vloss=read_vloss)
+        tile = child_stat_tile(tree, nodes, vloss=vloss)
         return step(st, pick(tile, depths, go))
 
     nodes, boards, depths, paths, n_empty, _ = jax.lax.while_loop(
@@ -362,17 +365,19 @@ def sync_iteration(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
 
     ``cp`` is the traced exploration constant (never read from cfg here —
     see GSCPMConfig). Selection runs the level-synchronous batched descent
-    by default; ``cfg.descent == "scalar"`` keeps the per-lane while-loop
-    oracle (same RNG schedule, bit-identical trees). Likewise the playout
-    phase defaults to the fused (W, cells) ``game.playout_batch`` and
-    ``cfg.playout == "scalar"`` keeps the per-lane ``game.playout_scalar``
-    oracle (bit-identical values under the same RNG schedule).
+    (``select_batch``) in ``vl_rounds`` rounds of W / R lanes; the playout
+    phase is the fused (W, cells) ``game.playout_batch``.
 
-    ``metrics`` (a ``repro.obsv.SearchMetrics`` accumulator, or None)
-    selects the return shape: with an accumulator the call returns
-    ``(tree, metrics)``; the metric updates are pure extra reductions over
-    values this function computes anyway — no RNG consumed, nothing fed
-    back — so the produced tree is bit-identical either way.
+    Virtual loss lives only inside this call: with R > 1 selection rounds a
+    zeroed (cap+1,) array rides the scan over the rounds, each round adds
+    its lanes' paths into it and the next round reads it; it is dropped at
+    the end. With R == 1 there is none.
+
+    ``metrics`` is a ``repro.obsv.SearchMetrics`` accumulator or None; the
+    call returns ``(tree, metrics)`` either way. The metric updates are pure
+    extra reductions over values this function computes anyway — no RNG
+    consumed, nothing fed back — so the produced tree is bit-identical with
+    or without them.
     """
     game = cfg.game_obj
     W = cfg.n_workers
@@ -381,54 +386,34 @@ def sync_iteration(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
         R -= 1
     Wr = W // R
 
-    def select_group(tree_r, keys_g):
-        # identical RNG schedule on both paths: per-lane (noise, move,
-        # playout) keys come from one split of the lane's iteration key
+    def round_body(vloss, inp):
+        keys_g, act_g = inp
+        # per-lane (noise, move, playout) keys from one split of the lane's
+        # iteration key: the RNG schedule the per-lane references share
         ks = jax.vmap(lambda k: jax.random.split(k, 3))(keys_g)
         k_noise, k_move, k_po = ks[:, 0], ks[:, 1], ks[:, 2]
-        if cfg.descent == "scalar":
-            def one(kn, km):
-                path, depth, leaf, board, n_empty = select_one(
-                    tree_r, root_board, game, cp, kn, cfg.select_noise)
-                mv = propose_move(tree_r, leaf, board, game, km)
-                return path, depth, leaf, board, mv
-            out = jax.vmap(one)(k_noise, k_move)
-        else:
-            # one selection round writes no virtual loss: it reads none
-            paths, depths, leaves, boards, _ = select_batch(
-                tree_r, root_board, game, cp, k_noise, cfg.select_noise,
-                read_vloss=R > 1)
-            mvs = jax.vmap(
-                lambda l, b, k: propose_move(tree_r, l, b, game, k)
-            )(leaves, boards, k_move)
-            out = (paths, depths, leaves, boards, mvs)
-        return (*out, k_po)
+        paths, depths, leaves, boards, _ = select_batch(
+            tree, root_board, game, cp, k_noise, cfg.select_noise,
+            vloss=vloss)
+        mvs = jax.vmap(
+            lambda l, b, k: propose_move(tree, l, b, game, k)
+        )(leaves, boards, k_move)
+        if vloss is not None:
+            with jax.named_scope("backup"):
+                vloss = add_vloss(vloss, paths, act_g.astype(jnp.float32),
+                                  cfg.virtual_loss)
+        return vloss, (paths, depths, leaves, boards, mvs, k_po)
 
     keys_r = iter_keys.reshape(R, Wr, *iter_keys.shape[1:])
     active_r = active.reshape(R, Wr)
-
-    # virtual loss only influences the NEXT selection round of this
-    # iteration; with a single round (R == 1) the add+reset pair is dead
-    # weight — skipping it is bit-identical (no RNG is consumed)
-    def round_body(tr, inp):
-        keys_g, act_g = inp
-        out = select_group(tr, keys_g)
-        paths = out[0]
-        if R > 1:
-            with jax.named_scope("backup"):
-                tr = add_vloss(tr, paths, act_g.astype(jnp.float32),
-                               cfg.virtual_loss)
-        return tr, out
 
     # one named scope per phase (descent, expand, leaf_eval, backup): the
     # compiled ops carry it in their op_name metadata, so a device trace
     # attributes each op to its phase; metadata only, the program's values
     # are the same. An op under two phases belongs to the inner one.
     with jax.named_scope("descent"):
-        tree, outs = jax.lax.scan(round_body, tree, (keys_r, active_r))
-    if R > 1:
-        with jax.named_scope("backup"):
-            tree = reset_vloss(tree)
+        vloss = jnp.zeros_like(tree.visits) if R > 1 else None
+        _, outs = jax.lax.scan(round_body, vloss, (keys_r, active_r))
 
     paths = outs[0].reshape(W, -1)
     depths = outs[1].reshape(W)
@@ -457,25 +442,53 @@ def sync_iteration(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
         placed = jax.vmap(game.place)(boards, jnp.maximum(moves, 0), movers)
         b2 = jnp.where(do[:, None], placed, boards)
         nxt = jnp.where(do, 3 - movers, movers)
-        if cfg.playout == "scalar":
-            # per-lane oracle: W interleaved scalar playouts under vmap
-            winners = jax.vmap(game.playout_scalar)(b2, nxt, po_keys)
-        else:
-            # fused leaf evaluation: ONE batched (W, cells) playout stage
-            # for all W lanes (bit-identical values to the oracle above —
-            # tests/test_game_protocol.py)
-            winners = game.playout_batch(b2, nxt, po_keys)
+        # fused leaf evaluation: ONE batched (W, cells) playout stage for
+        # all W lanes (bit-identical values to the per-lane
+        # ``game.playout_scalar`` — tests/test_game_protocol.py)
+        winners = game.playout_batch(b2, nxt, po_keys)
     with jax.named_scope("backup"):
         tree = backup_paths(tree, paths, winners, active.astype(jnp.float32))
-    if metrics is None:
-        return tree
-    from repro.obsv.search_metrics import accumulate_iteration
+    if metrics is not None:
+        from repro.obsv.search_metrics import accumulate_iteration
 
-    metrics = accumulate_iteration(
-        metrics, depths_grouped=outs[1], active=active, leaves=leaves,
-        moves=moves, eval_boards=b2, n_nodes_before=n_nodes_before,
-        n_nodes_after=tree.n_nodes)
+        metrics = accumulate_iteration(
+            metrics, depths_grouped=outs[1], active=active, leaves=leaves,
+            moves=moves, eval_boards=b2, n_nodes_before=n_nodes_before,
+            n_nodes_after=tree.n_nodes)
     return tree, metrics
+
+
+def chunk_body(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
+          task_keys: jnp.ndarray, active: jnp.ndarray, m: jnp.ndarray, cp,
+          metrics=None):
+    """The compiled round's body: ``m`` sync iterations of one tree.
+
+    Iteration ``i`` of task lane ``w`` draws from ``fold_in(task_keys[w],
+    i)``. ``metrics`` (a ``SearchMetrics`` accumulator or None) rides the
+    loop beside the tree; returns ``(tree, metrics)``. ``run_chunk`` jits
+    it, ``root_parallel`` runs it under ``jax.vmap`` for a forest and that
+    vmap under ``shard_map`` across chips. ``cfg.metrics`` must say whether
+    an accumulator rides along: the flag is hashed, so each arm is its own
+    compiled program.
+    """
+    if cfg.metrics != (metrics is not None):     # trace-time consistency
+        raise ValueError(
+            f"cfg.metrics={cfg.metrics} but metrics accumulator "
+            f"{'missing' if metrics is None else 'provided'} — pass "
+            "repro.obsv.init_search_metrics() iff the flag is set")
+
+    def body(i, carry):
+        tr, mx = carry
+        iter_keys = jax.vmap(lambda tk: jax.random.fold_in(tk, i))(task_keys)
+        return sync_iteration(tr, root_board, cfg, cp, iter_keys, active, mx)
+
+    return jax.lax.fori_loop(0, m, body, (tree, metrics))
+
+
+def chunk_result(tree, metrics):
+    """A chunk's return value: the tree alone, or ``(tree, metrics)`` where
+    an accumulator rides along."""
+    return tree if metrics is None else (tree, metrics)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(0,))
@@ -489,24 +502,8 @@ def run_chunk(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
     and the chunk returns ``(tree, metrics)`` — the flag is hashed, so a
     game class owns exactly TWO compiled programs: one per metrics arm.
     """
-    if cfg.metrics != (metrics is not None):     # trace-time consistency
-        raise ValueError(
-            f"cfg.metrics={cfg.metrics} but metrics accumulator "
-            f"{'missing' if metrics is None else 'provided'} — pass "
-            "repro.obsv.init_search_metrics() iff the flag is set")
-
-    def body(i, carry):
-        tr, mx = carry
-        iter_keys = jax.vmap(lambda tk: jax.random.fold_in(tk, i))(task_keys)
-        if cfg.metrics:
-            tr, mx = sync_iteration(tr, root_board, cfg, cp, iter_keys,
-                                    active, mx)
-        else:
-            tr = sync_iteration(tr, root_board, cfg, cp, iter_keys, active)
-        return tr, mx
-
-    tree, metrics = jax.lax.fori_loop(0, m, body, (tree, metrics))
-    return (tree, metrics) if cfg.metrics else tree
+    return chunk_result(*chunk_body(tree, root_board, cfg, task_keys,
+                                    active, m, cp, metrics))
 
 
 # ------------------------------------------------------------------ driver ----

@@ -409,7 +409,7 @@ def playout(
     The width-1 case of ``playout_batch`` (same fill stream, same winner
     dispatch). The genuinely-scalar formulation — per-lane flood-fill
     winner — survives as ``HexGame.playout_scalar``, the oracle the
-    bit-identity tests and the ``playout="scalar"`` search config use.
+    bit-identity tests compare whole searches against.
     """
     return playout_batch(board[None], to_move, key[None], spec)[0]
 

@@ -40,7 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import scheduler as sched
-from repro.core.gscpm import GSCPMConfig, fold_task_keys, sync_iteration
+from repro.core.gscpm import (GSCPMConfig, chunk_body, chunk_result,
+                              fold_task_keys)
 from repro.core.tree import (
     Tree,
     best_child,
@@ -57,70 +58,44 @@ from repro.obsv.trace import span
 def _forest_chunk(forest: Tree, boards: jnp.ndarray, cfg: GSCPMConfig,
                   task_keys: jnp.ndarray, active: jnp.ndarray,
                   m: jnp.ndarray, cp, metrics=None):
-    """`gscpm.run_chunk` vmapped over the ensemble axis — one program for E
-    trees. All members share the round's grain `m` and traced ``cp``;
-    per-member RNG streams keep their searches decorrelated. The batched
-    descent's ``ops.uct_select`` tile composes with this vmap (a leading E
-    axis on the (W, C) tiles — one fused (E·W, C) selection per level), and
-    so does the fused playout stage: the whole forest's leaf evaluations
-    become one (E·W, cells) batched ``game.playout_batch`` under vmap
-    (DESIGN.md §12/§13 — for Hex a single fill + connectivity solve with
-    one convergence loop) instead of E·W interleaved scalar while-loops.
-    ``cfg.metrics`` threads a per-member ``SearchMetrics`` accumulator
-    ((E,)-leaf pytree, ``init_search_metrics_forest``) through the same
-    vmap and returns ``(forest, metrics)``."""
-    if cfg.metrics != (metrics is not None):
-        raise ValueError(
-            "cfg.metrics and the metrics accumulator must agree: "
-            f"cfg.metrics={cfg.metrics}, metrics "
-            f"{'passed' if metrics is not None else 'omitted'}")
-
-    def one_tree(tree, board, keys, act, mx):
-        def body(i, carry):
-            tr, acc = carry
-            iter_keys = jax.vmap(lambda tk: jax.random.fold_in(tk, i))(keys)
-            if cfg.metrics:
-                tr, acc = sync_iteration(tr, board, cfg, cp, iter_keys,
-                                         act, acc)
-            else:
-                tr = sync_iteration(tr, board, cfg, cp, iter_keys, act)
-            return tr, acc
-
-        return jax.lax.fori_loop(0, m, body, (tree, mx))
-
-    if cfg.metrics:
-        return jax.vmap(one_tree)(forest, boards, task_keys, active, metrics)
-    forest, _ = jax.vmap(
-        lambda t, b, k, a: one_tree(t, b, k, a, 0))(
-            forest, boards, task_keys, active)
-    return forest
+    """``gscpm.chunk_body`` vmapped over the ensemble axis — one program for
+    E trees; returns ``(forest, metrics)``. All members share the round's
+    grain `m` and traced ``cp``; per-member RNG streams keep their searches
+    decorrelated. The batched descent's ``ops.uct_select`` tile composes
+    with this vmap (a leading E axis on the (W, C) tiles — one fused
+    (E·W, C) selection per level), and so does the fused playout stage: the
+    whole forest's leaf evaluations become one (E·W, cells) batched
+    ``game.playout_batch`` (DESIGN.md §12/§13). ``metrics`` is a per-member
+    ``SearchMetrics`` accumulator ((E,)-leaf pytree,
+    ``init_search_metrics_forest``) or None."""
+    return jax.vmap(
+        lambda t, b, k, a, mx: chunk_body(t, b, cfg, k, a, m, cp, mx))(
+            forest, boards, task_keys, active, metrics)
 
 
-run_chunk_forest = jax.jit(_forest_chunk, static_argnames=("cfg",),
-                           donate_argnums=(0,))
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(0,))
+def run_chunk_forest(forest: Tree, boards: jnp.ndarray, cfg: GSCPMConfig,
+                     task_keys: jnp.ndarray, active: jnp.ndarray,
+                     m: jnp.ndarray, cp, metrics=None):
+    """``_forest_chunk`` on one device: the forest, or ``(forest, metrics)``
+    where an accumulator rides along."""
+    return chunk_result(*_forest_chunk(forest, boards, cfg, task_keys,
+                                       active, m, cp, metrics))
+
+
+ENSEMBLE_SPEC = jax.sharding.PartitionSpec("ens")
 
 
 def ensemble_mesh(devices=None):
-    """The 1-D ensemble mesh over all visible devices (None on one device).
-
-    Built through ``launch.mesh.make_ensemble_mesh`` — the same
-    ``make_auto_mesh`` path as the LM production meshes, with the
-    ``"ens"`` axis the ``sharding/rules.py`` "ensemble" rule maps onto.
-    """
-    from repro.launch.mesh import make_ensemble_mesh
-
+    """The 1-D ``"ens"`` mesh over the given (default: all visible) devices,
+    every axis ``Auto``; None on one device. The forest's leading member
+    axis is sharded over it (``ENSEMBLE_SPEC``)."""
     devices = list(jax.devices() if devices is None else devices)
     if len(devices) <= 1:
         return None
-    return make_ensemble_mesh(devices)
-
-
-def ensemble_spec(mesh):
-    """``P("ens")`` for the forest's leading member axis, derived through
-    the logical-axis rules rather than spelled by hand."""
-    from repro.sharding.rules import DEFAULT_RULES, logical_to_spec
-
-    return logical_to_spec(("ensemble",), DEFAULT_RULES, mesh)
+    return jax.make_mesh((len(devices),), ("ens",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices)
 
 
 def ensemble_sharding(n_trees: int, mesh=None):
@@ -142,7 +117,7 @@ def ensemble_sharding(n_trees: int, mesh=None):
         return None, n_trees
     n_dev = int(np.prod(mesh.devices.shape))
     padded = ((n_trees + n_dev - 1) // n_dev) * n_dev
-    return jax.sharding.NamedSharding(mesh, ensemble_spec(mesh)), padded
+    return jax.sharding.NamedSharding(mesh, ENSEMBLE_SPEC), padded
 
 
 def pad_forest_members(forest: Tree, boards: jnp.ndarray, n_padded: int,
@@ -209,36 +184,27 @@ def place_forest(boards: jnp.ndarray, to_move, cfg: GSCPMConfig,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "mesh"),
                    donate_argnums=(0,))
-def _sharded_chunk(forest, boards, task_keys, active, m, cp, *, cfg, mesh):
+def _sharded_chunk(forest, boards, task_keys, active, m, cp, metrics=None, *,
+                   cfg, mesh):
     """``shard_map``-partitioned forest chunk: each device runs the vmapped
     per-round body (``_forest_chunk``, unchanged) on its own members with
     ZERO collectives — ``sync_root_stats``, dispatched outside this
     program, stays the only cross-shard exchange. Per-shard RNG is free:
     ``task_keys`` ride in pre-folded and sharded along the ensemble axis,
     so a member's stream is identical no matter which shard hosts it — the
-    bit-identity pin of tests/test_forest_sharding.py."""
-    spec, rep = ensemble_spec(mesh), jax.sharding.PartitionSpec()
+    bit-identity pin of tests/test_forest_sharding.py. An (E,)-leaf
+    ``SearchMetrics`` accumulator, where one rides along, takes the same
+    ensemble sharding (pad members see only masked-out work; callers slice
+    summaries to the real members) and the call returns
+    ``(forest, metrics)``."""
+    spec, rep = ENSEMBLE_SPEC, jax.sharding.PartitionSpec()
     body = jax.shard_map(
-        lambda f, b, k, a, mm, c: _forest_chunk(f, b, cfg, k, a, mm, c),
-        mesh=mesh, in_specs=(spec, spec, spec, spec, rep, rep),
-        out_specs=spec, check_vma=False)
-    return body(forest, boards, task_keys, active, m, cp)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "mesh"),
-                   donate_argnums=(0,))
-def _sharded_chunk_metrics(forest, boards, task_keys, active, m, cp, metrics,
-                           *, cfg, mesh):
-    """``_sharded_chunk`` with the (E,)-leaf ``SearchMetrics`` accumulator
-    riding the same ensemble sharding (pad members see only masked-out
-    work; callers slice summaries to the real members)."""
-    spec, rep = ensemble_spec(mesh), jax.sharding.PartitionSpec()
-    body = jax.shard_map(
-        lambda f, b, k, a, mm, c, mx: _forest_chunk(
-            f, b, cfg, k, a, mm, c, mx),
+        lambda f, b, k, a, mm, c, mx: _forest_chunk(f, b, cfg, k, a, mm, c,
+                                                    mx),
         mesh=mesh, in_specs=(spec, spec, spec, spec, rep, rep, spec),
         out_specs=(spec, spec), check_vma=False)
-    return body(forest, boards, task_keys, active, m, cp, metrics)
+    return chunk_result(*body(forest, boards, task_keys, active, m, cp,
+                              metrics))
 
 
 @jax.jit
@@ -276,11 +242,8 @@ def run_schedule_round_forest(forest: Tree, boards: jnp.ndarray,
     active = jnp.asarray(act)
     m = jnp.asarray(rnd.m, dtype=jnp.int32)
     if mesh is not None:
-        if cfg.metrics:
-            return _sharded_chunk_metrics(forest, boards, task_keys, active,
-                                          m, cp, metrics, cfg=cfg, mesh=mesh)
         return _sharded_chunk(forest, boards, task_keys, active, m, cp,
-                              cfg=cfg, mesh=mesh)
+                              metrics, cfg=cfg, mesh=mesh)
     return run_chunk_forest(forest, boards, cfg, task_keys, active, m, cp,
                             metrics)
 

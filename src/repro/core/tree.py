@@ -43,7 +43,6 @@ class Tree(NamedTuple):
     n_children: jnp.ndarray  # (cap+1,) i32
     visits: jnp.ndarray      # (cap+1,) f32  n_j
     wins: jnp.ndarray        # (cap+1,) f32  w_j
-    vloss: jnp.ndarray       # (cap+1,) f32  transient virtual-loss counts
     n_nodes: jnp.ndarray     # ()      i32  allocation counter (the paper's atomic index)
 
     @property
@@ -69,13 +68,8 @@ def init_tree(cap: int, max_children: int, root_to_move) -> Tree:
         n_children=jnp.zeros((cap + 1,), dtype=jnp.int32),
         visits=jnp.zeros((cap + 1,), dtype=jnp.float32),
         wins=jnp.zeros((cap + 1,), dtype=jnp.float32),
-        vloss=jnp.zeros((cap + 1,), dtype=jnp.float32),
         n_nodes=jnp.asarray(1, dtype=jnp.int32),
     )
-
-
-def reset_vloss(tree: Tree) -> Tree:
-    return tree._replace(vloss=jnp.zeros_like(tree.vloss))
 
 
 def backup_paths(tree: Tree, paths: jnp.ndarray, values: jnp.ndarray,
@@ -103,18 +97,21 @@ def backup_paths(tree: Tree, paths: jnp.ndarray, values: jnp.ndarray,
     return tree._replace(visits=visits, wins=wins)
 
 
-def add_vloss(tree: Tree, paths: jnp.ndarray, weights: jnp.ndarray,
-              amount: float = 1.0) -> Tree:
-    """Scatter virtual loss along selected paths (diversifies later rounds)."""
-    W, D = paths.shape
+def add_vloss(vloss: jnp.ndarray, paths: jnp.ndarray, weights: jnp.ndarray,
+              amount: float = 1.0) -> jnp.ndarray:
+    """Scatter virtual loss along selected paths (diversifies later rounds).
+
+    ``vloss`` is a (cap+1,) f32 array that lives only inside one sync
+    iteration (``gscpm.sync_iteration``); the tree itself holds none.
+    """
+    D = paths.shape[1]
+    cap = vloss.shape[-1] - 1
     flat = paths.reshape(-1)
-    w = jnp.repeat(weights, D) * (flat != tree.cap) * amount
-    vloss = tree.vloss.at[flat].add(w).at[tree.cap].set(0.0)
-    return tree._replace(vloss=vloss)
+    w = jnp.repeat(weights, D) * (flat != cap) * amount
+    return vloss.at[flat].add(w).at[cap].set(0.0)
 
 
-def child_stat_tile(tree: Tree, nodes: jnp.ndarray, *,
-                    read_vloss: bool = True):
+def child_stat_tile(tree: Tree, nodes: jnp.ndarray, vloss=None):
     """Gather the child statistics of a (W,) node batch as (W, C) tiles.
 
     Returns ``(safe, valid, wins, visits, vloss, parent_total)``: ``safe``
@@ -124,10 +121,10 @@ def child_stat_tile(tree: Tree, nodes: jnp.ndarray, *,
     level-synchronous ``kernels.ops.uct_select`` call — all W lanes of a
     descent score one tree level in a single (W, C) tile (DESIGN.md §11).
 
-    ``read_vloss=False`` is for a tree whose virtual loss is zero (a search
-    with one selection round per iteration never writes it): ``vloss`` is
-    not read at all, its tile is zeros made without a gather and the parent
-    total is the node's visits — the same values, bit for bit.
+    ``vloss`` is the iteration's (cap+1,) virtual-loss array, or None where
+    there is none (one selection round per iteration): the virtual-loss
+    tile is then zeros made without a gather and the parent total is the
+    node's visits.
     """
     C = tree.max_children
     cap = tree.cap
@@ -135,11 +132,11 @@ def child_stat_tile(tree: Tree, nodes: jnp.ndarray, *,
     valid = jnp.arange(C, dtype=jnp.int32)[None, :] < tree.n_children[nodes][:, None]
     safe = jnp.where(valid, slots, cap)
     wins, visits = tree.wins[safe], tree.visits[safe]
-    if not read_vloss:
+    if vloss is None:
         return (safe, valid, wins, visits, jnp.zeros_like(visits),
                 tree.visits[nodes])
-    parent_total = tree.visits[nodes] + tree.vloss[nodes]          # (W,)
-    return safe, valid, wins, visits, tree.vloss[safe], parent_total
+    parent_total = tree.visits[nodes] + vloss[nodes]               # (W,)
+    return safe, valid, wins, visits, vloss[safe], parent_total
 
 
 def best_child(tree: Tree) -> jnp.ndarray:
@@ -363,7 +360,6 @@ def _reroot_impl(tree: Tree, move: jnp.ndarray, new_cap: int) -> Tree:
         n_children=jnp.where(take, tree.n_children[src], 0),
         visits=jnp.where(take, tree.visits[src], 0.0),
         wins=jnp.where(take, tree.wins[src], 0.0),
-        vloss=jnp.zeros((new_cap + 1,), jnp.float32),
         n_nodes=jnp.maximum(n_sub, 1),
     )
 
@@ -406,8 +402,7 @@ def reroot_tree(tree: Tree, move, new_cap: int | None = None) -> Tree:
     exactly -1) are bit-identical to the corresponding node of the source
     tree. Rows outside the subtree are indistinguishable from a fresh
     ``init_tree``'s, so a search continuing from the result behaves exactly
-    like one hand-seeded with the retained statistics. Virtual loss is
-    transient per-search state and is cleared.
+    like one hand-seeded with the retained statistics.
 
     Re-rooting onto a move the root never expanded (or an unvisited child)
     yields a valid 1-node tree: root ``to_move`` flipped, zero statistics —

@@ -43,21 +43,6 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_auto_mesh(shape, axes, devices=devs[:need])
 
 
-def make_ensemble_mesh(devices=None):
-    """1-D mesh over the given (default: all visible) devices on axis
-    ``"ens"`` — the root-parallel forest's ensemble axis.
-
-    The multi-chip analogue of the paper's per-thread trees: members are
-    embarrassingly parallel, so the only mesh that matters is a flat
-    ensemble axis (``core/root_parallel.py`` shards E trees over it; on
-    CPU, force the 8-virtual-device mesh with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before any jax
-    import — see README "Scaling out").
-    """
-    devs = list(jax.devices() if devices is None else devices)
-    return make_auto_mesh((len(devs),), ("ens",), devices=devs)
-
-
 def make_host_mesh(model_axis: int | None = None):
     """Best-effort mesh over whatever devices exist (tests, examples).
 

@@ -111,9 +111,10 @@ def select_token_path(tree: Tree, cfg: MCTSDecodeConfig, noise_key: jax.Array,
         slots = tree.children[node]
         valid = jnp.arange(C, dtype=jnp.int32) < n_kids
         safe = jnp.where(valid, slots, cap)
+        visits = tree.visits[safe]
         scores = uct_mod.uct_scores(
-            tree.wins[safe], tree.visits[safe], tree.vloss[safe],
-            tree.visits[node] + tree.vloss[node], cp, valid)
+            tree.wins[safe], visits, jnp.zeros_like(visits),
+            tree.visits[node], cp, valid)
         noise = cfg.select_noise * jax.random.uniform(
             jax.random.fold_in(noise_key, depth), (C,))
         child = safe[uct_mod.select_child(scores, noise)]

@@ -34,10 +34,6 @@ DEFAULT_RULES: dict[str, Any] = {
     "layers": None,
     "conv": None,
     "lora": None,
-    # root-parallel MCTS: the forest's leading member axis splits over the
-    # 1-D ensemble mesh (launch.mesh.make_ensemble_mesh); on LM meshes
-    # (no "ens" axis) logical_to_spec drops it, so the rule is inert there
-    "ensemble": "ens",
 }
 
 _state = threading.local()

@@ -10,8 +10,6 @@ traced knobs (Cp, grain, scheduler) must never grow the jit caches.
 
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,9 +17,11 @@ import pytest
 
 from repro.core import hex as hx
 from repro.core.gscpm import (GSCPMConfig, expand_batch, gscpm_search,
-                              run_chunk, select_batch, select_one)
+                              run_chunk, select_batch, select_one,
+                              sync_iteration)
 from repro.core.root_parallel import gscpm_search_batch
-from repro.core.tree import best_child, child_stat_tile, init_tree, reroot_tree
+from repro.core.tree import (Tree, best_child, child_stat_tile, init_tree,
+                             reroot_tree)
 from repro.kernels import ops
 from repro.kernels import uct_select as _us
 from repro.serve.mcts_decode import (MCTSDecodeConfig, backup_values,
@@ -91,58 +91,86 @@ def test_select_batch_matches_vmapped_select_one(size, W, noise_scale, kind):
 
 
 def test_select_batch_reads_no_vloss_when_told():
-    """With ``read_vloss=False`` a NaN virtual loss changes no path: it is
-    never read. Read, the same NaN does change them."""
-    tree, board, spec = built_tree(5, jax.random.PRNGKey(4))
-    nan = tree._replace(vloss=jnp.full_like(tree.vloss, jnp.nan))
+    """With no virtual-loss operand ``select_batch`` reads none: on a grown
+    tree its paths are bit-identical to those it takes with an explicit
+    zeros operand. An operand given is read: a NaN one changes them."""
+    tree, board, spec = built_tree(3, jax.random.PRNGKey(4))
     keys = jax.random.split(jax.random.PRNGKey(9), 16)
     cp = jnp.float32(1.0)
-    want = select_batch(tree, board, spec, cp, keys, 1e-3)
-    got = select_batch(nan, board, spec, cp, keys, 1e-3, read_vloss=False)
+    want = select_batch(tree, board, spec, cp, keys, 1e-3,
+                        vloss=jnp.zeros_like(tree.visits))
+    got = select_batch(tree, board, spec, cp, keys, 1e-3)
+    assert int(np.asarray(want[1]).max()) >= 2    # tiles below the root
     for name, w, g in zip(("path", "depth", "leaf", "board", "n_empty"),
                           want, got):
         np.testing.assert_array_equal(np.asarray(w), np.asarray(g),
                                       err_msg=f"{name} diverged")
-    read = select_batch(nan, board, spec, cp, keys, 1e-3)
+    read = select_batch(tree, board, spec, cp, keys, 1e-3,
+                        vloss=jnp.full_like(tree.visits, jnp.nan))
     assert not np.array_equal(np.asarray(read[0]), np.asarray(want[0]))
 
 
+def sole_unvisited_root(cfg: GSCPMConfig):
+    """A 3x3 Hex tree whose root is fully expanded: eight children won
+    every one of their 100 playouts, the ninth (the last, node 9) was never
+    visited."""
+    game = cfg.game_obj
+    n = game.n_cells
+    tree = init_tree(cfg.tree_cap, game.n_actions, 1)
+    tree, _ = expand_batch(tree, jnp.zeros((n,), jnp.int32),
+                           jnp.arange(n, dtype=jnp.int32),
+                           jnp.ones((n,), bool))
+    played = (jnp.arange(tree.cap + 1) >= 1) & (jnp.arange(tree.cap + 1) < n)
+    visits = jnp.where(played, 100.0, 0.0).at[0].set(100.0 * (n - 1))
+    return tree._replace(visits=visits, wins=jnp.where(played, 100.0, 0.0))
+
+
 def test_run_chunk_one_round_reads_no_vloss():
-    """At ``vl_rounds == 1`` the compiled round never reads ``vloss``: a
-    tree holding NaN there grows exactly as one holding zeros."""
-    board = hx.empty_board(hx.HexSpec(5))
-    cfg = GSCPMConfig(board_size=5, n_workers=8, tree_cap=1024)
-    key = jax.random.PRNGKey(21)
-    task_keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(
-        jnp.arange(8, dtype=jnp.int32))
-    active = jnp.ones((8,), bool)
-    grown = []
-    for fill in (0.0, jnp.nan):
-        tree = init_tree(cfg.tree_cap, 25, 1)
-        tree = tree._replace(vloss=jnp.full_like(tree.vloss, fill))
-        grown.append(run_chunk(tree, board, cfg, task_keys, active,
-                               jnp.int32(24), jnp.float32(1.0)))
-    zero, nan = grown
-    assert int(zero.n_nodes) > 100
-    for f in ("parent", "move", "to_move", "children", "n_children",
-              "visits", "wins", "n_nodes"):
-        np.testing.assert_array_equal(np.asarray(getattr(zero, f)),
-                                      np.asarray(getattr(nan, f)), err_msg=f)
+    """Virtual loss lives inside one sync iteration. At ``vl_rounds == 3``
+    (one lane per round) the first round takes the root's sole unvisited
+    child; the rounds after it see that round's virtual loss there and
+    avoid it, so one playout passes through it, not three as with one
+    round. The next iteration starts with none: two iterations of
+    ``run_chunk`` equal two fresh ``sync_iteration`` calls in turn."""
+    board = hx.empty_board(hx.HexSpec(3))
+    cp = jnp.float32(0.1)
+    active = jnp.ones((3,), bool)
+    task_keys = jax.random.split(jax.random.PRNGKey(21), 3)
+    keys_at = lambda i: jax.vmap(                         # noqa: E731
+        lambda k: jax.random.fold_in(k, i))(task_keys)
+    through_sole = {}
+    for rounds in (1, 3):
+        cfg = GSCPMConfig(board_size=3, n_workers=3, vl_rounds=rounds,
+                          tree_cap=64)
+        step = jax.jit(lambda t, k, cfg=cfg: sync_iteration(
+            t, board, cfg, cp, k, active)[0])
+        tree = sole_unvisited_root(cfg)
+        one = step(tree, keys_at(0))
+        through_sole[rounds] = float(one.visits[9])
+        two = step(one, keys_at(1))
+        chunk = run_chunk(jax.tree.map(jnp.copy, tree), board, cfg,
+                          task_keys, active, jnp.int32(2), cp)
+        for f in Tree._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(two, f)),
+                                          np.asarray(getattr(chunk, f)),
+                                          err_msg=f"{rounds} rounds: {f}")
+    assert through_sole == {1: 3.0, 3: 1.0}
 
 
 @pytest.mark.parametrize("vl_rounds", [1, 3])
 @pytest.mark.parametrize("W,noise", [(4, 1e-3), (8, 0.0), (8, 1e-3)])
-def test_full_search_batched_equals_scalar(vl_rounds, W, noise):
+def test_full_search_batched_equals_scalar(vl_rounds, W, noise,
+                                           per_lane_references):
     """Whole searches — selection, expansion, playout, backup — produce
-    bit-identical trees whichever descent runs (same RNG schedule)."""
+    bit-identical trees whichever descent runs (same RNG schedule): the
+    batched one, or the per-lane ``select_one`` swapped in."""
     board = hx.empty_board(hx.HexSpec(5))
-    base = GSCPMConfig(board_size=5, n_playouts=128, n_tasks=8, n_workers=W,
-                       vl_rounds=vl_rounds, select_noise=noise,
-                       tree_cap=2048, descent="batched")
+    cfg = GSCPMConfig(board_size=5, n_playouts=128, n_tasks=8, n_workers=W,
+                      vl_rounds=vl_rounds, select_noise=noise, tree_cap=2048)
     key = jax.random.PRNGKey(17)
-    t_b, s_b = gscpm_search(board, 1, base, key)
-    t_s, s_s = gscpm_search(board, 1,
-                            dataclasses.replace(base, descent="scalar"), key)
+    t_b, s_b = gscpm_search(board, 1, cfg, key)
+    with per_lane_references(cfg.game_obj, descent=True):
+        t_s, s_s = gscpm_search(board, 1, cfg, key)
     assert int(t_b.n_nodes) == int(t_s.n_nodes)
     nn = int(t_b.n_nodes)
     for f in ("parent", "move", "to_move", "n_children"):
@@ -225,9 +253,9 @@ def test_child_stat_tile_matches_scalar_gather():
         np.testing.assert_array_equal(np.asarray(safe[i]), s)
         np.testing.assert_allclose(np.asarray(wins[i]),
                                    np.asarray(tree.wins)[s])
-        np.testing.assert_allclose(
-            np.asarray(ptot[i]),
-            float(tree.visits[n]) + float(tree.vloss[n]))
+        np.testing.assert_allclose(np.asarray(ptot[i]),
+                                   float(tree.visits[n]))
+        assert not np.asarray(vloss[i]).any()
 
 
 # --------------------------------------------------------- compile counts ----

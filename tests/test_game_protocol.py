@@ -15,7 +15,6 @@ core free of direct game imports.
 
 from __future__ import annotations
 
-import dataclasses
 import inspect
 
 import jax
@@ -293,16 +292,17 @@ def test_property_search_invariants_every_game(seed, name, workers):
 
 
 @pytest.mark.parametrize("name", GAMES)
-def test_full_search_scalar_paths_bit_identical(name):
-    """descent/playout oracle configs survive the seam for every game."""
+def test_full_search_scalar_paths_bit_identical(name, per_lane_references):
+    """The per-lane descent and playout references, swapped in, reproduce
+    the batched search through the seam for every game."""
     g = make(name)
     base = GSCPMConfig(game=name, board_size=GAME_SIZES[name], n_playouts=64,
                        n_tasks=8, n_workers=4, tree_cap=2048)
     key = jax.random.PRNGKey(29)
     t0, s0 = gscpm_search(g.init_board(), 1, base, key)
-    for repl in ({"playout": "scalar"}, {"descent": "scalar"}):
-        t1, s1 = gscpm_search(g.init_board(), 1,
-                              dataclasses.replace(base, **repl), key)
+    for repl in ({"playout": True}, {"descent": True}):
+        with per_lane_references(base.game_obj, **repl):
+            t1, s1 = gscpm_search(g.init_board(), 1, base, key)
         nn = int(t0.n_nodes)
         assert nn == int(t1.n_nodes), repl
         for f in ("parent", "move", "to_move", "n_children"):

@@ -71,7 +71,6 @@ def test_vl_rounds_invariants():
     board = hx.empty_board(hx.HexSpec(SIZE))
     tree, stats = gscpm_search(board, 1, cfg(n_workers=8, vl_rounds=4), key)
     check_invariants(tree)
-    assert np.asarray(tree.vloss).sum() == 0.0  # vloss reset after each step
 
 
 def test_root_visits_equal_budget_fifo():

@@ -12,8 +12,6 @@ fixed-round variant.
 
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -209,18 +207,19 @@ def test_replay_moves_matches_sequential_placement(n_moves):
 
 # ------------------------------------------------------- search integration ----
 @pytest.mark.parametrize("W", [4, 8])
-def test_full_search_playout_batched_equals_scalar(W):
+def test_full_search_playout_batched_equals_scalar(W, per_lane_references):
     """Whole GSCPM searches with the fused playout stage produce bit-identical
-    trees to the per-lane flood-fill playout oracle (same RNG schedule)."""
+    trees to the per-lane flood-fill playout reference swapped in (same RNG
+    schedule)."""
     from repro.core.gscpm import GSCPMConfig, gscpm_search
 
     board = hx.empty_board(hx.HexSpec(5))
-    base = GSCPMConfig(board_size=5, n_playouts=128, n_tasks=8, n_workers=W,
-                       tree_cap=2048, playout="batched")
+    cfg = GSCPMConfig(board_size=5, n_playouts=128, n_tasks=8, n_workers=W,
+                      tree_cap=2048)
     key = jax.random.PRNGKey(23)
-    t_b, s_b = gscpm_search(board, 1, base, key)
-    t_s, s_s = gscpm_search(board, 1,
-                            dataclasses.replace(base, playout="scalar"), key)
+    t_b, s_b = gscpm_search(board, 1, cfg, key)
+    with per_lane_references(cfg.game_obj, playout=True):
+        t_s, s_s = gscpm_search(board, 1, cfg, key)
     assert int(t_b.n_nodes) == int(t_s.n_nodes)
     nn = int(t_b.n_nodes)
     for f in ("parent", "move", "to_move", "n_children"):

@@ -151,14 +151,13 @@ def test_forest_programs_compile_for_four_chips(topo, monkeypatch):
     from repro.core import root_parallel as rp
     from repro.core.gscpm import fold_task_keys
     from repro.core.tree import init_forest
-    from repro.launch.mesh import make_ensemble_mesh
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(PAPER, n_playouts=124_928, n_tasks=488,
                               tree_cap=1 << 17)
     E, W, M = 8, cfg.n_workers, cfg.game_obj.n_actions
-    mesh = make_ensemble_mesh(list(topo.devices))
-    ens = NamedSharding(mesh, rp.ensemble_spec(mesh))
+    mesh = rp.ensemble_mesh(list(topo.devices))
+    ens = NamedSharding(mesh, rp.ENSEMBLE_SPEC)
     rep = NamedSharding(mesh, PartitionSpec())
     place = lambda x: _spec(x.shape, x.dtype, ens)  # noqa: E731
     forest = jax.tree.map(place, jax.eval_shape(
