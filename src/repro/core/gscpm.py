@@ -54,6 +54,7 @@ from repro.core.tree import (
     best_child,
     child_stat_tile,
     init_tree,
+    path_levels,
     root_value,
 )
 from repro.kernels import ops
@@ -453,8 +454,9 @@ def sync_iteration(tree: Tree, root_board: jnp.ndarray, cfg: GSCPMConfig,
 
         metrics = accumulate_iteration(
             metrics, depths_grouped=outs[1], active=active, leaves=leaves,
-            moves=moves, eval_boards=b2, n_nodes_before=n_nodes_before,
-            n_nodes_after=tree.n_nodes)
+            moves=moves, eval_boards=b2,
+            backup_levels=path_levels(paths, tree.cap),
+            n_nodes_before=n_nodes_before, n_nodes_after=tree.n_nodes)
     return tree, metrics
 
 

@@ -72,6 +72,18 @@ def init_tree(cap: int, max_children: int, root_to_move) -> Tree:
     )
 
 
+def path_levels(paths: jnp.ndarray, cap: int) -> jnp.ndarray:
+    """How many leading columns of a (W, D) path array can hold a node: the
+    last column with an entry other than the pad row ``cap``, plus one."""
+    held = (paths != cap).any(axis=0)
+    return jnp.max(jnp.where(held, jnp.arange(1, paths.shape[1] + 1), 0))
+
+
+# path columns the backup scatters per trip of its level loop, W x K
+# entries each (chosen from v5e timings, PERF.md §6)
+_BACKUP_BLOCK = 4
+
+
 def backup_paths(tree: Tree, paths: jnp.ndarray, values: jnp.ndarray,
                  weights: jnp.ndarray) -> Tree:
     """Batched backpropagation — the scatter-add analogue of atomic w_j/n_j.
@@ -79,21 +91,37 @@ def backup_paths(tree: Tree, paths: jnp.ndarray, values: jnp.ndarray,
     paths:   (W, max_depth) i32 node ids, PAD (== cap) where unused
     values:  (W,) playout outcomes: winning player (1|2) or 0 for a DRAW
     weights: (W,) f32 1.0 for active lanes, 0.0 for masked lanes
+
+    Only the leading ``path_levels`` columns can hold a node, so a loop
+    scatters those, ``_BACKUP_BLOCK`` columns per trip, and never the pad
+    columns below the deepest lane. The sums are those of one scatter over
+    every column: visits are counts and wins multiples of 0.5, exact in f32
+    in any order below 2^24.
     """
     W, D = paths.shape
-    flat = paths.reshape(-1)
-    # credit: 1 if the player who moved into the node won the playout,
-    # 0.5 on a draw (the value every player is indifferent to — keeps
-    # X_j = w_j / n_j in [0, 1] with 0.5 as the draw point)
-    mover = 3 - tree.to_move[flat]  # (W*D,)
-    vals = jnp.repeat(values.astype(jnp.int32), D)
-    win = jnp.where(vals == 0, 0.5, (mover == vals).astype(jnp.float32))
-    w = jnp.repeat(weights, D) * (flat != tree.cap)  # mask pads & inactive lanes
-    visits = tree.visits.at[flat].add(w)
-    wins = tree.wins.at[flat].add(w * win)
+    K, cap = _BACKUP_BLOCK, tree.cap
+    # trip t covers columns t*K .. t*K+K-1, column-major: (trips, K*W)
+    blocks = jnp.pad(paths.T, ((0, -D % K), (0, 0)),
+                     constant_values=cap).reshape(-1, K * W)
+    vals = jnp.tile(values.astype(jnp.int32), K)
+    lane_w = jnp.tile(weights, K)
+
+    def trip(t, stats):
+        visits, wins = stats
+        nodes = blocks[t]
+        # credit: 1 if the player who moved into the node won the playout,
+        # 0.5 on a draw (the value every player is indifferent to — keeps
+        # X_j = w_j / n_j in [0, 1] with 0.5 as the draw point)
+        mover = 3 - tree.to_move[nodes]
+        win = jnp.where(vals == 0, 0.5, (mover == vals).astype(jnp.float32))
+        w = lane_w * (nodes != cap)  # mask pads & inactive lanes
+        return visits.at[nodes].add(w), wins.at[nodes].add(w * win)
+
+    trips = (path_levels(paths, cap) + K - 1) // K
+    visits, wins = jax.lax.fori_loop(0, trips, trip, (tree.visits, tree.wins))
     # pad row may have accumulated; zero it for hygiene
-    visits = visits.at[tree.cap].set(0.0)
-    wins = wins.at[tree.cap].set(0.0)
+    visits = visits.at[cap].set(0.0)
+    wins = wins.at[cap].set(0.0)
     return tree._replace(visits=visits, wins=wins)
 
 

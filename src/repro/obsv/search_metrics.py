@@ -49,6 +49,9 @@ class SearchMetrics(NamedTuple):
     # (W, C) child-statistics tiles the level loop gathered: one per level
     # below the root from which some lane stepped (gscpm.select_batch)
     tile_gathers: jnp.ndarray
+    # path levels the backup scattered: the leading columns of the
+    # iteration's (W, D) paths that hold a node (tree.path_levels)
+    backup_levels: jnp.ndarray
     expand_proposals: jnp.ndarray       # (leaf, move) expansion proposals
     expansions: jnp.ndarray             # nodes actually allocated
     expand_collisions: jnp.ndarray      # duplicate proposals collapsed
@@ -118,6 +121,7 @@ def _pair_dup_count(leaves: jnp.ndarray, moves: jnp.ndarray,
 def accumulate_iteration(m: SearchMetrics, *, depths_grouped: jnp.ndarray,
                          active: jnp.ndarray, leaves: jnp.ndarray,
                          moves: jnp.ndarray, eval_boards: jnp.ndarray,
+                         backup_levels: jnp.ndarray,
                          n_nodes_before: jnp.ndarray,
                          n_nodes_after: jnp.ndarray) -> SearchMetrics:
     """Fold one ``sync_iteration``'s observations into the accumulator.
@@ -133,6 +137,7 @@ def accumulate_iteration(m: SearchMetrics, *, depths_grouped: jnp.ndarray,
     - ``eval_boards``: (W, n_cells) positions handed to the playout stage
       (its empty count IS the playout length — the fill stage plays until
       the board is full);
+    - ``backup_levels``: the path levels the backup scattered;
     - ``n_nodes_before/after``: allocation counter around ``expand_batch``.
     """
     from repro.core.game import EMPTY
@@ -159,6 +164,7 @@ def accumulate_iteration(m: SearchMetrics, *, depths_grouped: jnp.ndarray,
         depth_max=jnp.maximum(m.depth_max, (depths * act_i).max()),
         held_levels=m.held_levels + held,
         tile_gathers=m.tile_gathers + tiles,
+        backup_levels=m.backup_levels + backup_levels,
         expand_proposals=m.expand_proposals + proposals,
         expansions=m.expansions + (n_nodes_after - n_nodes_before),
         expand_collisions=m.expand_collisions
@@ -202,6 +208,8 @@ def summarize_metrics(m: SearchMetrics) -> dict:
     host["depth_mean"] = host["depth_sum"] / playouts
     host["tile_gathers_per_iter"] = (
         host["tile_gathers"] / max(1, host["sync_iterations"]))
+    host["backup_levels_per_iter"] = (
+        host["backup_levels"] / max(1, host["sync_iterations"]))
     host["playout_len_mean"] = host["playout_moves"] / playouts
     host["expand_collision_rate"] = (
         host["expand_collisions"] / max(1, host["expand_proposals"]))

@@ -180,15 +180,20 @@ def test_forest_summary_merges_members():
 def test_merge_metrics_sum_vs_max_fields():
     a = init_search_metrics()._replace(
         expansions=jnp.int32(3), tree_nodes_peak=jnp.int32(10),
-        tile_gathers=jnp.int32(5), sync_iterations=jnp.int32(2))
+        tile_gathers=jnp.int32(5), backup_levels=jnp.int32(9),
+        sync_iterations=jnp.int32(2))
     b = init_search_metrics()._replace(
         expansions=jnp.int32(4), tree_nodes_peak=jnp.int32(8),
-        tile_gathers=jnp.int32(2), sync_iterations=jnp.int32(2))
+        tile_gathers=jnp.int32(2), backup_levels=jnp.int32(8),
+        sync_iterations=jnp.int32(2))
     c = merge_metrics(a, b)
     assert int(c.expansions) == 7
     assert int(c.tree_nodes_peak) == 10
     assert int(c.tile_gathers) == 7                 # summed counter
-    assert summarize_metrics(c)["tile_gathers_per_iter"] == 7 / 4
+    assert int(c.backup_levels) == 17               # summed counter
+    s = summarize_metrics(c)
+    assert s["tile_gathers_per_iter"] == 7 / 4
+    assert s["backup_levels_per_iter"] == 17 / 4
 
 
 @pytest.mark.parametrize("vl_rounds", [1, 2])
@@ -236,6 +241,48 @@ def test_tile_gathers_counts_levels_stepped_from(monkeypatch, vl_rounds):
     if vl_rounds == 1:
         assert stepped[0] == 0                      # root not yet expanded
         assert m["tile_gathers"] == sum(stepped)
+
+
+def test_backup_levels_counts_the_levels_backed_up(monkeypatch):
+    """``backup_levels`` is the path levels the backup scattered: the
+    leading columns of the paths it was handed that hold a node (counted
+    here from those paths on the host), which is the deepest descent + 1,
+    + 2 where a lane at that depth expanded."""
+    from repro.core import gscpm
+
+    touched = []
+    real = gscpm.backup_paths
+
+    def counting(tree, paths, values, weights):
+        def record(p):
+            touched.append(int(np.flatnonzero((p != tree.cap).any(0))[-1]) + 1)
+        jax.debug.callback(record, paths)
+        return real(tree, paths, values, weights)
+
+    monkeypatch.setattr(gscpm, "backup_paths", counting)
+    W = 8
+    cfg = cfg_for("hex", metrics=True, n_workers=W, board_size=3,
+                  tree_cap=2048)
+    board = cfg.game_obj.init_board()
+    one = jax.jit(lambda t, k, mx: gscpm.sync_iteration(
+        t, board, cfg, jnp.float32(1.0), k, jnp.ones((W,), bool), mx))
+    tree = init_tree(cfg.tree_cap, cfg.game_obj.n_actions, 1)
+    total = init_search_metrics()
+    levels, deepest = [], []
+    for i in range(60):
+        keys = jax.random.split(jax.random.key(i), W)
+        _, mx = one(tree, keys, init_search_metrics())
+        tree, total = one(tree, keys, total)
+        m = summarize_metrics(mx)
+        levels.append(m["backup_levels"])
+        deepest.append(m["depth_max"])
+    jax.effects_barrier()
+    assert levels == touched[::2] == touched[1::2]
+    assert summarize_metrics(total)["backup_levels"] == sum(levels)
+    assert all(d + 1 <= n <= d + 2 for n, d in zip(levels, deepest))
+    assert levels[0] == 2                            # the root expands
+    assert max(deepest) >= 2 and \
+        sum(n == d + 2 for n, d in zip(levels, deepest)) > 0
 
 
 # ------------------------------------------------------------------ tracer ----

@@ -18,6 +18,7 @@ written for a chip that is not attached cannot be read back here.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import re
 
@@ -96,6 +97,41 @@ HEX_WINNER_CALL = re.compile(
     r'custom_call_target="tpu_custom_call"', re.M)
 
 
+HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)(.*)$")
+
+
+def backup_index_entries(text):
+    """Entries each gather and scatter of the backup phase moves (a
+    gather's result, a scatter's updates). A fused scatter may carry no
+    ``op_name`` of its own; its combiner, made for the backup's
+    scatter-add, does."""
+    size, body, comp = {}, {}, None
+    for line in text.splitlines():
+        if m := HLO_COMPUTATION.match(line):
+            comp = body.setdefault(m.group(1), [])
+        elif m := HLO_INSTRUCTION.match(line):
+            name, shape, opcode, args, rest = m.groups()
+            size[name] = math.prod(int(d) for d in shape.split(",") if d)
+            comp.append((name, opcode, args.split(", "), rest))
+
+    def combiner_in_backup(rest):
+        region = re.search(r"to_apply=%([\w.-]+)", rest)
+        return bool(region) and any("/backup/" in r
+                                    for *_, r in body[region.group(1)])
+
+    found = {}
+    for ops in body.values():
+        for name, opcode, args, rest in ops:
+            if opcode == "gather" and "/backup/" in rest:
+                found[name] = size[name]
+            elif opcode == "scatter" and ("/backup/" in rest
+                                          or combiner_in_backup(rest)):
+                found[name] = size[args[2].lstrip("%")]
+    return sorted(found.values())
+
+
 @pytest.mark.parametrize("size,W", [(11, 244), (9, 16), (13, 64),
                                     (11, 1024)])
 def test_hex_winner_compiles_for_tpu(one_chip, size, W):
@@ -135,6 +171,11 @@ def test_paper_search_program_compiles_for_tpu(one_chip, monkeypatch):
     tiles = re.findall(rf"= f32\[{cfg.n_workers * game.n_actions}\]\S* "
                        r"fusion\(.*descent/.*gather", text)
     assert len(tiles) == 2, tiles
+    # the backup scatters a few path levels per trip, never all W x
+    # (max_moves + 1) = 29,768 entries of the padded path array
+    entries = backup_index_entries(text)
+    assert len(entries) >= 3, entries             # to_move gather, 2 scatters
+    assert max(entries) < cfg.n_workers * (game.max_moves + 1), entries
     mem = compiled.memory_analysis()
     # the donated tree is updated in place; scratch stays far below HBM
     assert mem.alias_size_in_bytes >= 0.99 * mem.argument_size_in_bytes
@@ -181,6 +222,10 @@ def test_forest_programs_compile_for_four_chips(topo, monkeypatch):
     assert len(HEX_WINNER_CALL.findall(text)) == 1
     assert text.count("tpu_custom_call") >= 2
     assert not COLLECTIVE.search(text)
+    # two members per chip, and no backup op at even one's full width
+    entries = backup_index_entries(text)
+    assert len(entries) >= 3, entries
+    assert max(entries) < W * (cfg.game_obj.max_moves + 1), entries
     mem = chunk.memory_analysis()
     assert mem.alias_size_in_bytes >= 0.99 * 2 * member_bytes
     assert mem.temp_size_in_bytes < 1 << 30
