@@ -143,9 +143,10 @@ def kernel_phase(cfg, seed: int, n_boards: int = N_BOARDS) -> None:
 def check_tree(tree, playouts: int) -> None:
     """Vectorized structural checks of a cold-searched tree: conservation
     at the root, parents allocated before children, alternating sides,
-    half-integer wins within [0, visits], and no node whose children hold
-    more visits than it does."""
-    from repro.core.tree import root_summary
+    half-integer wins within [0, visits], no node whose children hold
+    more visits than it does, and each node's child-move row equal to its
+    children's moves."""
+    from repro.core.tree import NO_NODE, root_summary
     from repro.serve.resilience import validate_result
 
     n = int(tree.n_nodes)
@@ -167,6 +168,13 @@ def check_tree(tree, playouts: int) -> None:
     if n > 1 and (np.bincount(parent[1:], weights=visits[1:], minlength=n)
                   > visits).any():
         bad.append("children hold more visits than their parent")
+    children, child_move = (np.asarray(a[:n]) for a in (tree.children,
+                                                        tree.child_move))
+    held = (np.arange(children.shape[1])[None, :]
+            < np.asarray(tree.n_children[:n])[:, None])
+    moves = np.asarray(tree.move)[np.where(held, children, 0)]
+    if not (child_move == np.where(held, moves, NO_NODE)).all():
+        bad.append("child_move rows differ from the children's moves")
     if bad:
         raise SmokeFailure("tree checks failed: " + "; ".join(bad))
 
@@ -189,7 +197,7 @@ def host_reference_check(cfg, seed: int) -> None:
                                jax.random.key(seed))
         return {k: np.asarray(getattr(tree, k)) for k in (
             "n_nodes", "visits", "wins", "parent", "move", "children",
-            "n_children")}
+            "child_move", "n_children")}
 
     on_device = tree_arrays()
     real_backend = jax.default_backend

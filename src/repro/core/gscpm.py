@@ -277,13 +277,11 @@ def propose_move(tree: Tree, leaf: jnp.ndarray, board: jnp.ndarray,
     there, so won/drawn positions are evaluated in place, never grown.
     """
     n_cells = game.n_cells
-    C = tree.max_children
-    cap = tree.cap
     legal = game.legal_mask(board)
-    slots = tree.children[leaf]
-    valid = jnp.arange(C, dtype=jnp.int32) < tree.n_children[leaf]
-    tried_moves = jnp.where(valid, tree.move[jnp.where(valid, slots, cap)], n_cells)
-    tried = jnp.zeros((n_cells + 1,), dtype=bool).at[tried_moves].set(True)[:n_cells]
+    # one row of the leaf's child moves; the NO_NODE pads match no cell
+    tried_moves = tree.child_move[leaf]                            # (C,)
+    tried = (tried_moves[:, None]
+             == jnp.arange(n_cells, dtype=jnp.int32)[None, :]).any(axis=0)
     untried = legal & ~tried
     # argmax of iid uniforms over the untried set IS a uniform choice — the
     # gumbel transform (two transcendental maps) buys nothing here
@@ -341,6 +339,8 @@ def expand_batch(tree: Tree, leaves: jnp.ndarray, moves: jnp.ndarray,
         jnp.where(alloc, 3 - tree.to_move[leaf_s], 0))
     children = tree.children.at[src_leaf, jnp.where(alloc, slot, 0)].set(
         jnp.where(alloc, new_id_s, tree.children[src_leaf, jnp.where(alloc, slot, 0)]))
+    child_move = tree.child_move.at[src_leaf, jnp.where(alloc, slot, 0)].set(
+        jnp.where(alloc, move_s, NO_NODE))
     n_children = tree.n_children.at[src_leaf].add(alloc.astype(jnp.int32))
     n_new = alloc.sum().astype(jnp.int32)
 
@@ -350,7 +350,8 @@ def expand_batch(tree: Tree, leaves: jnp.ndarray, moves: jnp.ndarray,
     n_children = n_children.at[cap].set(0)
 
     tree = tree._replace(parent=parent, move=move_arr, to_move=to_move,
-                         children=children, n_children=n_children,
+                         children=children, child_move=child_move,
+                         n_children=n_children,
                          n_nodes=tree.n_nodes + n_new)
     # map back to worker order: duplicates get their first occurrence's id
     per_sorted = jnp.where(valid_s & can, new_id_s, cap)

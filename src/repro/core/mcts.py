@@ -43,6 +43,8 @@ def uct_iteration(tree: Tree, root_board: jnp.ndarray, game,
         to_move=tree.to_move.at[new].set(jnp.where(did, 3 - tree.to_move[leaf], 0)),
         children=tree.children.at[tgt_leaf, jnp.where(did, slot, 0)].set(
             jnp.where(did, new, tree.children[tgt_leaf, jnp.where(did, slot, 0)])),
+        child_move=tree.child_move.at[tgt_leaf, jnp.where(did, slot, 0)].set(
+            jnp.where(did, mv, NO_NODE)),
         n_children=tree.n_children.at[tgt_leaf].add(did.astype(jnp.int32)),
         n_nodes=tree.n_nodes + did.astype(jnp.int32),
     )

@@ -40,6 +40,10 @@ class Tree(NamedTuple):
     move: jnp.ndarray        # (cap+1,) i32  move from parent that made this node
     to_move: jnp.ndarray     # (cap+1,) i32  player to move at this node (1|2)
     children: jnp.ndarray    # (cap+1, max_children) i32, NO_NODE padded
+    # (cap+1, max_children) i32, NO_NODE padded: child_move[p, s] ==
+    # move[children[p, s]] on every slot s < n_children[p], so a node's tried
+    # moves are one row, read as ``children[p]`` is
+    child_move: jnp.ndarray
     n_children: jnp.ndarray  # (cap+1,) i32
     visits: jnp.ndarray      # (cap+1,) f32  n_j
     wins: jnp.ndarray        # (cap+1,) f32  w_j
@@ -65,6 +69,8 @@ def init_tree(cap: int, max_children: int, root_to_move) -> Tree:
         .at[0]
         .set(jnp.asarray(root_to_move, dtype=jnp.int32)),
         children=jnp.full((cap + 1, max_children), NO_NODE, dtype=jnp.int32),
+        child_move=jnp.full((cap + 1, max_children), NO_NODE,
+                            dtype=jnp.int32),
         n_children=jnp.zeros((cap + 1,), dtype=jnp.int32),
         visits=jnp.zeros((cap + 1,), dtype=jnp.float32),
         wins=jnp.zeros((cap + 1,), dtype=jnp.float32),
@@ -385,6 +391,8 @@ def _reroot_impl(tree: Tree, move: jnp.ndarray, new_cap: int) -> Tree:
         move=mv_arr.astype(jnp.int32),
         to_move=to_move.astype(jnp.int32),
         children=children,
+        # moves are cells, not node ids: retained rows carry over as they are
+        child_move=jnp.where(take[:, None], tree.child_move[src], NO_NODE),
         n_children=jnp.where(take, tree.n_children[src], 0),
         visits=jnp.where(take, tree.visits[src], 0.0),
         wins=jnp.where(take, tree.wins[src], 0.0),
@@ -500,6 +508,8 @@ def check_reroot_retention(src: Tree, dst: Tree, move: int) -> int:
             assert int(s.move[u]) == int(d.move[k])
             assert new_of_old[int(s.parent[u])] == int(d.parent[k])
         assert int(s.n_children[u]) == int(d.n_children[k])
+        assert (s.child_move[u] == d.child_move[k]).all(), \
+            f"child moves differ at node {u}->{k}"
         su = {int(new_of_old[int(c)])
               for c in s.children[u][: int(s.n_children[u])]}
         du = set(d.children[k][: int(d.n_children[k])].tolist())
@@ -564,6 +574,10 @@ def check_invariants(tree: Tree, *, discrete_credits: bool = True) -> None:
         assert (kids >= 0).all() and (kids < n).all(), f"node {i}: invalid child ids"
         moves = t.move[kids]
         assert len(set(moves.tolist())) == k, f"node {i}: duplicate child moves"
+        assert (t.child_move[i][:k] == moves).all(), \
+            f"node {i}: child_move row != move[children]"
+        assert (t.child_move[i][k:] == NO_NODE).all(), \
+            f"node {i}: stale child_move slots"
         assert (t.parent[kids] == i).all(), f"node {i}: child parent mismatch"
         assert (t.children[i][k:] == NO_NODE).all(), f"node {i}: stale child slots"
         # visits of children never exceed the parent's visits
@@ -576,6 +590,8 @@ def check_invariants(tree: Tree, *, discrete_credits: bool = True) -> None:
         if discrete_credits:
             assert abs(2.0 * t.wins[i] - round(2.0 * float(t.wins[i]))) < 1e-4, \
                 f"node {i}: wins {t.wins[i]} not a half-integer credit sum"
+    assert (t.child_move[n:] == NO_NODE).all(), \
+        "child_move rows of unallocated nodes or the pad row are set"
     # every allocated non-root node is some node's child exactly once
     all_kids = []
     for i in range(n):

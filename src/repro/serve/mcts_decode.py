@@ -179,12 +179,8 @@ def propose_token(tree: Tree, leaf: jnp.ndarray, leaf_logits: jnp.ndarray,
                   cfg: MCTSDecodeConfig, depth: jnp.ndarray,
                   key: jax.Array) -> jnp.ndarray:
     """Random untried token among the leaf's top-`branch` logits (-1: none)."""
-    C = tree.max_children
-    cap = tree.cap
     _, top_tok = jax.lax.top_k(leaf_logits, cfg.branch)   # (branch,)
-    slots = tree.children[leaf]
-    valid = jnp.arange(C, dtype=jnp.int32) < tree.n_children[leaf]
-    tried = jnp.where(valid, tree.move[jnp.where(valid, slots, cap)], -1)
+    tried = tree.child_move[leaf]          # (C,), NO_NODE past its children
     is_tried = (top_tok[:, None] == tried[None, :]).any(axis=1)  # (branch,)
     can = ~is_tried & (depth < cfg.max_depth)
     g = jax.random.gumbel(key, (cfg.branch,))

@@ -78,7 +78,8 @@ def test_serving_phase_tiny(capsys):
 
 
 @pytest.mark.parametrize("corrupt", ["root_visits", "wins_above_visits",
-                                     "child_heavier_than_parent"])
+                                     "child_heavier_than_parent",
+                                     "stale_child_move"])
 def test_check_tree_rejects_a_corrupted_tree(corrupt):
     tree, st = gscpm_search(TINY.game_obj.init_board(), 1, TINY,
                             jax.random.key(1))
@@ -87,8 +88,10 @@ def test_check_tree_rejects_a_corrupted_tree(corrupt):
         tree = tree._replace(visits=tree.visits.at[0].add(1.0))
     elif corrupt == "wins_above_visits":
         tree = tree._replace(wins=tree.wins.at[1].set(tree.visits[1] + 1))
-    else:
+    elif corrupt == "child_heavier_than_parent":
         tree = tree._replace(visits=tree.visits.at[2].add(2 * st["playouts"]))
+    else:
+        tree = tree._replace(child_move=tree.child_move.at[0, 0].add(1))
     with pytest.raises(smoke.SmokeFailure):
         smoke.check_tree(tree, st["playouts"])
 

@@ -99,7 +99,7 @@ HEX_WINNER_CALL = re.compile(
 
 HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
 HLO_INSTRUCTION = re.compile(
-    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)(.*)$")
+    r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)(.*)$")
 
 
 def backup_index_entries(text):
@@ -112,7 +112,7 @@ def backup_index_entries(text):
         if m := HLO_COMPUTATION.match(line):
             comp = body.setdefault(m.group(1), [])
         elif m := HLO_INSTRUCTION.match(line):
-            name, shape, opcode, args, rest = m.groups()
+            name, _, shape, opcode, args, rest = m.groups()
             size[name] = math.prod(int(d) for d in shape.split(",") if d)
             comp.append((name, opcode, args.split(", "), rest))
 
@@ -130,6 +130,31 @@ def backup_index_entries(text):
                                           or combiner_in_backup(rest)):
                 found[name] = size[args[2].lstrip("%")]
     return sorted(found.values())
+
+
+def move_row_reads(text, cap, lanes, n_cells, members=1):
+    """The two ops by which a proposal once read a leaf's tried moves: a
+    descent gather of ``lanes`` x ``n_cells`` single elements of the
+    per-node (cap+1,) ``move`` array, and a scatter of them into a
+    (lanes, n_cells + 1) bool mask; ``members`` trees per chip multiply
+    both. Returns the names of such ops."""
+    typed, found = {}, []
+    for line in text.splitlines():
+        if m := HLO_INSTRUCTION.match(line):
+            name, dtype, shape, opcode, args, rest = m.groups()
+            dims = tuple(int(d) for d in shape.split(",") if d)
+            typed[name] = (dtype, dims)
+            operand = typed.get(args.split(", ")[0].lstrip("%"))
+            if (opcode == "gather" and "/descent/" in rest
+                    and math.prod(dims) == members * lanes * n_cells
+                    and operand is not None and operand[0] == "s32"
+                    and operand[1][-1:] == (cap + 1,)
+                    and len(operand[1]) <= 2):
+                found.append(name)
+            elif (opcode == "scatter" and dtype == "pred"
+                  and math.prod(dims) == members * lanes * (n_cells + 1)):
+                found.append(name)
+    return found
 
 
 @pytest.mark.parametrize("size,W", [(11, 244), (9, 16), (13, 64),
@@ -176,8 +201,13 @@ def test_paper_search_program_compiles_for_tpu(one_chip, monkeypatch):
     entries = backup_index_entries(text)
     assert len(entries) >= 3, entries             # to_move gather, 2 scatters
     assert max(entries) < cfg.n_workers * (game.max_moves + 1), entries
+    # a proposal reads its leaf's tried moves as one child_move row: no
+    # W x C = 29,524-element gather of `move`, no 29,768-entry tried scatter
+    reads = move_row_reads(text, cfg.tree_cap, cfg.n_workers, game.n_cells)
+    assert not reads, reads
     mem = compiled.memory_analysis()
-    # the donated tree is updated in place; scratch stays far below HBM
+    # the donated tree (child_move included) is updated in place; scratch
+    # stays far below HBM
     assert mem.alias_size_in_bytes >= 0.99 * mem.argument_size_in_bytes
     assert mem.temp_size_in_bytes < 1 << 30
 
@@ -226,6 +256,10 @@ def test_forest_programs_compile_for_four_chips(topo, monkeypatch):
     entries = backup_index_entries(text)
     assert len(entries) >= 3, entries
     assert max(entries) < W * (cfg.game_obj.max_moves + 1), entries
+    # nor a move gather or tried scatter for either member
+    reads = move_row_reads(text, cfg.tree_cap, W, cfg.game_obj.n_cells,
+                           members=E // len(topo.devices))
+    assert not reads, reads
     mem = chunk.memory_analysis()
     assert mem.alias_size_in_bytes >= 0.99 * 2 * member_bytes
     assert mem.temp_size_in_bytes < 1 << 30
